@@ -58,7 +58,8 @@ def main() -> None:
                     help="directory for the BENCH_*.json files")
     args = ap.parse_args()
 
-    from repro.runtime import telemetry
+    from repro.runtime import compile_cache, telemetry
+    compile_cache.enable()
 
     from . import (bench_autotune, bench_bcsr, bench_fault, bench_levels,
                    bench_load_balance, bench_mesh2d, bench_mismatch,

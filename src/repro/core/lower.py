@@ -337,8 +337,11 @@ class LoweredKernel:
                     meas = (f" measured={c['measured_s']:.3e}s"
                             if c.get("measured_s") is not None else "")
                     mark = " <- winner" if c["label"] == t.label else ""
+                    err = (f" FAILED {c['error']}" if c.get("error")
+                           else "")
                     lines.append(f"    {i + 1:2d}. {c['label']:<28s} "
-                                 f"est={c['est_cost_s']:.3e}s{meas}{mark}")
+                                 f"est={c['est_cost_s']:.3e}s{meas}{mark}"
+                                 f"{err}")
         else:
             lines.append("  hand-picked schedule (no candidate search ran)")
         comm = self.comm

@@ -50,9 +50,13 @@ from .tdn import Machine
 from .tensor import Tensor
 from .tin import Assignment
 from ..kernels.autotune import TuneResult, tune_block_ell
-from ..launch.roofline import DEFAULT_HW, HardwareModel
+from ..launch.roofline import V5E, HardwareModel, hardware_model
 
 log = logging.getLogger(__name__)
+
+# The chip the cost model plans for, named: the planner scores candidates
+# against v5e peaks whatever backend the process runs on.
+PLAN_HW = hardware_model(V5E)
 
 # Winner memoization: (signature, machine dim sizes, weights fingerprint,
 # per-operand (name, content fingerprint, index vars)) -> SchedulePoint
@@ -124,9 +128,12 @@ class SchedulePoint:
     replicated: bool = False         # 2.5-D: sparse operand replicated on z
     est_cost_s: float = float("inf")
     measured_s: Optional[float] = None
+    # Why scoring or timing this point failed ("<ExcType>: <message>"); a
+    # failed point keeps an infinite cost and stays in the provenance.
+    error: Optional[str] = None
     # Set on the WINNER only: every point the search scored, as plain
-    # dicts (label / est_cost_s / measured_s) in model-cost order — the
-    # provenance LoweredKernel.explain() renders, kept picklable so
+    # dicts (label / est_cost_s / measured_s / error) in model-cost order
+    # — the provenance LoweredKernel.explain() renders, kept picklable so
     # checkpointed tuned entries carry it.
     candidates: Optional[List[Dict[str, Any]]] = None
 
@@ -378,7 +385,7 @@ def _replicated_nnz(stmt: Assignment) -> Tuple[List[Tensor], bool]:
 
 
 def estimate(stmt: Assignment, point: SchedulePoint, stats: StructStats,
-             hw: HardwareModel = DEFAULT_HW) -> float:
+             hw: HardwareModel = PLAN_HW) -> float:
     """Roofline-style score in seconds: max(compute, HBM) + network.
 
     Per-device work is the padded maximum over pieces — universe splits
@@ -458,7 +465,7 @@ def _measure(stmt: Assignment, point: SchedulePoint, base: Machine,
 def search(stmt: Assignment, machine: Machine, *,
            weights=None, jit: bool = True,
            config: Optional[SearchConfig] = None,
-           hw: HardwareModel = DEFAULT_HW) -> Optional[SchedulePoint]:
+           hw: HardwareModel = PLAN_HW) -> Optional[SchedulePoint]:
     """Enumerate, score, optionally measure, and return the winning
     point (None when nothing could be scored)."""
     cfg = config or DEFAULT_CONFIG
@@ -474,9 +481,10 @@ def search(stmt: Assignment, machine: Machine, *,
         for p in points:
             try:
                 p.est_cost_s = estimate(stmt, p, stats, hw)
-            except Exception:                    # estimator gap: deprioritize
+            except Exception as e:               # estimator gap: deprioritize
                 log.exception("plan_search: estimate failed for %s", p.label)
                 p.est_cost_s = float("inf")
+                p.error = f"estimate: {type(e).__name__}: {e}"
         points.sort(key=lambda p: p.est_cost_s)
         if cfg.refine_top_k > 0 and len(points) > 1:
             for p in points[:cfg.refine_top_k]:
@@ -486,10 +494,15 @@ def search(stmt: Assignment, machine: Machine, *,
                         p.measured_s = _measure(stmt, p, machine, weights,
                                                 jit, cfg)
                         msp.set(measured_s=p.measured_s)
-                except Exception:
+                except Exception as e:
+                    # Recorded, not swallowed: the candidate carries the
+                    # error into explain() and the provenance, where a
+                    # caller that requires a clean search (chip_smoke.py)
+                    # fails on it.
                     log.exception("plan_search: measurement failed for %s",
                                   p.label)
                     p.measured_s = float("inf")
+                    p.error = f"measure: {type(e).__name__}: {e}"
             measured = [p for p in points if p.measured_s is not None]
             measured.sort(key=lambda p: p.measured_s)
             winner = measured[0]
@@ -500,7 +513,8 @@ def search(stmt: Assignment, machine: Machine, *,
         winner.candidates = [
             {"label": p.label, "est_cost_s": p.est_cost_s,
              "measured_s": (None if p.measured_s is None
-                            else float(p.measured_s))}
+                            else float(p.measured_s)),
+             "error": p.error}
             for p in points]
         search_sp.set(winner=winner.label, n_candidates=len(points))
     log.info("plan_search: %s -> %s (est %.3es, measured %s)",

@@ -22,7 +22,6 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
 
-from ..compat import shard_map
 from ..core.cache import LRUCache, avals_key
 from ..core.lower import LoweredKernel
 from ..core.tdn import Machine
@@ -54,16 +53,23 @@ def _mesh_key(mesh: Mesh):
             tuple(d.id for d in mesh.devices.flat))
 
 
-def _spmd_runner(name, mesh, axis, static, arrays, build):
-    """Return the jitted shard_map executable for a builder, reusing a
-    cached one when (builder, mesh, axis, statics, shard avals) match."""
+def _spmd_runner(name, mesh, axis, static, in_specs, arrays, build):
+    """Return the jitted shard_map executable for a builder — reusing a
+    cached one when (builder, mesh, axis, statics, shard avals) match —
+    and the builder's arrays placed ONCE on the mesh, each under its
+    ``in_specs`` entry as a NamedSharding. Every call then runs on arrays
+    that already live where the program reads them: nothing lands on the
+    first device and reshards inside the program per call."""
     key = (name, _mesh_key(mesh), axis, tuple(static), avals_key(arrays))
 
     def _jit_build():
         with telemetry.span("lower.jit", leaf=name, spmd=True):
             return jax.jit(build())
 
-    return _SPMD_RUN_CACHE.get_or_build(key, _jit_build)
+    run = _SPMD_RUN_CACHE.get_or_build(key, _jit_build)
+    placed = tuple(jax.device_put(a, NamedSharding(mesh, s))
+                   for a, s in zip(arrays, in_specs))
+    return run, placed
 
 
 def _assemble_vals(total, out_vals, arrays, vals_bounds):
@@ -93,28 +99,23 @@ def spmv_rows_spmd(kernel: LoweredKernel, mesh: Mesh, axis: str = "x"):
     c = kernel.shards[kernel.stmt.rhs.accesses()[1].tensor.name]
     n = kernel.stmt.lhs.tensor.shape[0]
     a = B.arrays
-    max_rows = B.meta["max_rows"]
+    specs = (P(axis), P(axis), P(axis), P())
 
     def build():
-        @functools.partial(
-            shard_map, mesh=mesh,
-            in_specs=(P(axis), P(axis), P(axis), P(), P(axis)),
-            out_specs=P(axis))
-        def run(pos, crd, vals, cvec, row_count):
+        @functools.partial(jax.shard_map, mesh=mesh, in_specs=specs,
+                           out_specs=P(axis))
+        def run(pos, crd, vals, cvec):
             # leading shard axis has local extent 1 inside shard_map
             y = K.leaf_spmv_rows(pos[0], crd[0], vals[0], cvec)
             return y[None]
         return run
 
-    run = _spmd_runner(
-        "spmv_rows", mesh, axis, (),
-        (a["pos1"], a["crd1"], a["vals"], c.arrays["vals"], a["row_count"]),
-        build)
+    run, args = _spmd_runner(
+        "spmv_rows", mesh, axis, (), specs,
+        (a["pos1"], a["crd1"], a["vals"], c.arrays["vals"]), build)
 
     def call():
-        y_blocks = run(jnp.asarray(a["pos1"]), jnp.asarray(a["crd1"]),
-                       jnp.asarray(a["vals"]), jnp.asarray(c.arrays["vals"]),
-                       jnp.asarray(a["row_count"]))
+        y_blocks = run(*args)
         # assemble global output (disjoint row blocks)
         out = np.zeros(n, np.float32)
         rb = np.asarray(a["row_start"])
@@ -124,6 +125,7 @@ def spmv_rows_spmd(kernel: LoweredKernel, mesh: Mesh, axis: str = "x"):
             out[rb[p]: rb[p] + cnt[p]] = yb[p, : cnt[p]]
         return out
 
+    call.placed = args
     return call
 
 
@@ -136,25 +138,24 @@ def spmv_nnz_spmd(kernel: LoweredKernel, mesh: Mesh, axis: str = "x"):
     n = kernel.stmt.lhs.tensor.shape[0]
     a = B.arrays
 
+    specs = (P(axis), P(axis), P(axis), P())
+
     def build():
-        @functools.partial(
-            shard_map, mesh=mesh,
-            in_specs=(P(axis), P(axis), P(axis), P()),
-            out_specs=P())
+        @functools.partial(jax.shard_map, mesh=mesh, in_specs=specs,
+                           out_specs=P())
         def run(rows, cols, vals, cvec):
             y = K.leaf_spmv_nnz(rows[0], cols[0], vals[0], cvec, n)
             return jax.lax.psum(y, axis_name=axis)
         return run
 
-    run = _spmd_runner(
-        "spmv_nnz", mesh, axis, (n,),
+    run, args = _spmd_runner(
+        "spmv_nnz", mesh, axis, (n,), specs,
         (a["dim0"], a["dim1"], a["vals"], c.arrays["vals"]), build)
 
     def call():
-        return np.asarray(run(
-            jnp.asarray(a["dim0"]), jnp.asarray(a["dim1"]),
-            jnp.asarray(a["vals"]), jnp.asarray(c.arrays["vals"])))
+        return np.asarray(run(*args))
 
+    call.placed = args
     return call
 
 
@@ -167,29 +168,28 @@ def spmm_rows_spmd(kernel: LoweredKernel, mesh: Mesh, axis: str = "x"):
     n, J = kernel.stmt.lhs.tensor.shape
     a = B.arrays
 
+    specs = (P(axis), P(axis), P(axis), P())
+
     def build():
-        @functools.partial(
-            shard_map, mesh=mesh,
-            in_specs=(P(axis), P(axis), P(axis), P()),
-            out_specs=P(axis))
+        @functools.partial(jax.shard_map, mesh=mesh, in_specs=specs,
+                           out_specs=P(axis))
         def run(pos, crd, vals, Cm):
             return K.leaf_spmm_rows(pos[0], crd[0], vals[0], Cm)[None]
         return run
 
-    run = _spmd_runner(
-        "spmm_rows", mesh, axis, (),
+    run, args = _spmd_runner(
+        "spmm_rows", mesh, axis, (), specs,
         (a["pos1"], a["crd1"], a["vals"], C.arrays["vals"]), build)
 
     def call():
-        yb = np.asarray(run(jnp.asarray(a["pos1"]), jnp.asarray(a["crd1"]),
-                            jnp.asarray(a["vals"]),
-                            jnp.asarray(C.arrays["vals"])))
+        yb = np.asarray(run(*args))
         out = np.zeros((n, J), np.float32)
         rs, cnt = np.asarray(a["row_start"]), np.asarray(a["row_count"])
         for p in range(yb.shape[0]):
             out[rs[p]: rs[p] + cnt[p]] = yb[p, : cnt[p]]
         return out
 
+    call.placed = args
     return call
 
 
@@ -203,29 +203,27 @@ def sddmm_nnz_spmd(kernel: LoweredKernel, mesh: Mesh, axis: str = "x"):
     D = kernel.shards[accs[2].tensor.name]
     a = B.arrays
 
+    specs = (P(axis), P(axis), P(axis), P(), P())
+
     def build():
-        @functools.partial(
-            shard_map, mesh=mesh,
-            in_specs=(P(axis), P(axis), P(axis), P(), P()),
-            out_specs=P(axis))
+        @functools.partial(jax.shard_map, mesh=mesh, in_specs=specs,
+                           out_specs=P(axis))
         def run(rows, cols, vals, Cm, Dm):
             return K.leaf_sddmm_nnz(rows[0], cols[0], vals[0], Cm, Dm)[None]
         return run
 
-    run = _spmd_runner(
-        "sddmm_nnz", mesh, axis, (),
+    run, args = _spmd_runner(
+        "sddmm_nnz", mesh, axis, (), specs,
         (a["dim0"], a["dim1"], a["vals"], C.arrays["vals"],
          D.arrays["vals"]), build)
 
     def call():
-        out_vals = np.asarray(run(
-            jnp.asarray(a["dim0"]), jnp.asarray(a["dim1"]),
-            jnp.asarray(a["vals"]), jnp.asarray(C.arrays["vals"]),
-            jnp.asarray(D.arrays["vals"])))
+        out_vals = np.asarray(run(*args))
         Bt = accs[0].tensor
         return _assemble_vals(Bt.nnz, out_vals, a,
                               kernel.plans[Bt.name].vals_bounds)
 
+    call.placed = args
     return call
 
 
@@ -240,27 +238,25 @@ def spmm_nnz_spmd(kernel: LoweredKernel, mesh: Mesh, axis: str = "x"):
     n = kernel.stmt.lhs.tensor.shape[0]
     a = B.arrays
     sp = sparse_pspecs({"B": B, "C": C}, axis)
+    specs = (sp["B"]["dim0"], sp["B"]["dim1"], sp["B"]["vals"],
+             sp["C"]["vals"])
 
     def build():
-        @functools.partial(
-            shard_map, mesh=mesh,
-            in_specs=(sp["B"]["dim0"], sp["B"]["dim1"], sp["B"]["vals"],
-                      sp["C"]["vals"]),
-            out_specs=P())
+        @functools.partial(jax.shard_map, mesh=mesh, in_specs=specs,
+                           out_specs=P())
         def run(rows, cols, vals, Cm):
             y = K.leaf_spmm_nnz(rows[0], cols[0], vals[0], Cm, n)
             return jax.lax.psum(y, axis_name=axis)
         return run
 
-    run = _spmd_runner(
-        "spmm_nnz", mesh, axis, (n,),
+    run, args = _spmd_runner(
+        "spmm_nnz", mesh, axis, (n,), specs,
         (a["dim0"], a["dim1"], a["vals"], C.arrays["vals"]), build)
 
     def call():
-        return np.asarray(run(
-            jnp.asarray(a["dim0"]), jnp.asarray(a["dim1"]),
-            jnp.asarray(a["vals"]), jnp.asarray(C.arrays["vals"])))
+        return np.asarray(run(*args))
 
+    call.placed = args
     return call
 
 
@@ -276,31 +272,28 @@ def sddmm_rows_spmd(kernel: LoweredKernel, mesh: Mesh, axis: str = "x"):
     Bt = accs[0].tensor
     a = B.arrays
     sp = sparse_pspecs({"B": B, "C": C, "D": D}, axis)
+    specs = (sp["B"]["pos1"], sp["B"]["crd1"], sp["B"]["vals"],
+             sp["C"]["vals"], sp["D"]["vals"])
 
     def build():
-        @functools.partial(
-            shard_map, mesh=mesh,
-            in_specs=(sp["B"]["pos1"], sp["B"]["crd1"], sp["B"]["vals"],
-                      sp["C"]["vals"], sp["D"]["vals"]),
-            out_specs=P(axis))
+        @functools.partial(jax.shard_map, mesh=mesh, in_specs=specs,
+                           out_specs=P(axis))
         def run(pos, crd, vals, Cl, Dm):
             return K.leaf_sddmm_rows(pos[0], crd[0], vals[0], Cl[0],
                                      Dm)[None]
         return run
 
-    run = _spmd_runner(
-        "sddmm_rows", mesh, axis, (),
+    run, args = _spmd_runner(
+        "sddmm_rows", mesh, axis, (), specs,
         (a["pos1"], a["crd1"], a["vals"], C.arrays["vals"],
          D.arrays["vals"]), build)
 
     def call():
-        out_vals = np.asarray(run(
-            jnp.asarray(a["pos1"]), jnp.asarray(a["crd1"]),
-            jnp.asarray(a["vals"]), jnp.asarray(C.arrays["vals"]),
-            jnp.asarray(D.arrays["vals"])))
+        out_vals = np.asarray(run(*args))
         return _assemble_vals(Bt.nnz, out_vals, a,
                               kernel.plans[Bt.name].vals_bounds)
 
+    call.placed = args
     return call
 
 
@@ -316,28 +309,28 @@ def bcsr_spmv_rows_spmd(kernel: LoweredKernel, mesh: Mesh, axis: str = "x"):
     c_blk = pack_vec_blocks(np.asarray(c.arrays["vals"]),
                             int(B.meta["grid_cols"]), int(B.meta["bc"]))
 
+    specs = (P(axis), P(axis), P(axis), P())
+
     def build():
-        @functools.partial(
-            shard_map, mesh=mesh,
-            in_specs=(P(axis), P(axis), P(axis), P()),
-            out_specs=P(axis))
+        @functools.partial(jax.shard_map, mesh=mesh, in_specs=specs,
+                           out_specs=P(axis))
         def run(pos, crd, tiles, cb):
             return K.leaf_bcsr_spmv_rows(pos[0], crd[0], tiles[0], cb)[None]
         return run
 
-    run = _spmd_runner(
-        "bcsr_spmv_rows", mesh, axis, (),
+    run, args = _spmd_runner(
+        "bcsr_spmv_rows", mesh, axis, (), specs,
         (a["pos1"], a["crd1"], a["vals"], c_blk), build)
 
     def call():
-        yb = np.asarray(run(jnp.asarray(a["pos1"]), jnp.asarray(a["crd1"]),
-                            jnp.asarray(a["vals"]), jnp.asarray(c_blk)))
+        yb = np.asarray(run(*args))
         out = np.zeros(n, np.float32)
         rs, cnt = np.asarray(a["row_start"]), np.asarray(a["row_count"])
         for p in range(yb.shape[0]):
             out[rs[p]: rs[p] + cnt[p]] = yb[p, : cnt[p]]
         return out
 
+    call.placed = args
     return call
 
 
@@ -353,25 +346,24 @@ def bcsr_spmv_nnz_spmd(kernel: LoweredKernel, mesh: Mesh, axis: str = "x"):
     c_blk = pack_vec_blocks(np.asarray(c.arrays["vals"]),
                             int(B.meta["grid_cols"]), int(B.meta["bc"]))
 
+    specs = (P(axis), P(axis), P(axis), P())
+
     def build():
-        @functools.partial(
-            shard_map, mesh=mesh,
-            in_specs=(P(axis), P(axis), P(axis), P()),
-            out_specs=P())
+        @functools.partial(jax.shard_map, mesh=mesh, in_specs=specs,
+                           out_specs=P())
         def run(bd0, bd1, tiles, cb):
             y = K.leaf_bcsr_spmv_nnz(bd0[0], bd1[0], tiles[0], cb, gr)
             return jax.lax.psum(y, axis_name=axis)
         return run
 
-    run = _spmd_runner(
-        "bcsr_spmv_nnz", mesh, axis, (gr,),
+    run, args = _spmd_runner(
+        "bcsr_spmv_nnz", mesh, axis, (gr,), specs,
         (a["bdim0"], a["bdim1"], a["vals"], c_blk), build)
 
     def call():
-        y = np.asarray(run(jnp.asarray(a["bdim0"]), jnp.asarray(a["bdim1"]),
-                           jnp.asarray(a["vals"]), jnp.asarray(c_blk)))
-        return y[:n]
+        return np.asarray(run(*args))[:n]
 
+    call.placed = args
     return call
 
 
@@ -387,28 +379,28 @@ def bcsr_spmm_rows_spmd(kernel: LoweredKernel, mesh: Mesh, axis: str = "x"):
     C_blk = pack_mat_row_blocks(np.asarray(C.arrays["vals"]),
                                 int(B.meta["grid_cols"]), int(B.meta["bc"]))
 
+    specs = (P(axis), P(axis), P(axis), P())
+
     def build():
-        @functools.partial(
-            shard_map, mesh=mesh,
-            in_specs=(P(axis), P(axis), P(axis), P()),
-            out_specs=P(axis))
+        @functools.partial(jax.shard_map, mesh=mesh, in_specs=specs,
+                           out_specs=P(axis))
         def run(pos, crd, tiles, Cb):
             return K.leaf_bcsr_spmm_rows(pos[0], crd[0], tiles[0], Cb)[None]
         return run
 
-    run = _spmd_runner(
-        "bcsr_spmm_rows", mesh, axis, (),
+    run, args = _spmd_runner(
+        "bcsr_spmm_rows", mesh, axis, (), specs,
         (a["pos1"], a["crd1"], a["vals"], C_blk), build)
 
     def call():
-        yb = np.asarray(run(jnp.asarray(a["pos1"]), jnp.asarray(a["crd1"]),
-                            jnp.asarray(a["vals"]), jnp.asarray(C_blk)))
+        yb = np.asarray(run(*args))
         out = np.zeros((n, J), np.float32)
         rs, cnt = np.asarray(a["row_start"]), np.asarray(a["row_count"])
         for p in range(yb.shape[0]):
             out[rs[p]: rs[p] + cnt[p]] = yb[p, : cnt[p]]
         return out
 
+    call.placed = args
     return call
 
 
@@ -424,25 +416,24 @@ def bcsr_spmm_nnz_spmd(kernel: LoweredKernel, mesh: Mesh, axis: str = "x"):
     C_blk = pack_mat_row_blocks(np.asarray(C.arrays["vals"]),
                                 int(B.meta["grid_cols"]), int(B.meta["bc"]))
 
+    specs = (P(axis), P(axis), P(axis), P())
+
     def build():
-        @functools.partial(
-            shard_map, mesh=mesh,
-            in_specs=(P(axis), P(axis), P(axis), P()),
-            out_specs=P())
+        @functools.partial(jax.shard_map, mesh=mesh, in_specs=specs,
+                           out_specs=P())
         def run(bd0, bd1, tiles, Cb):
             y = K.leaf_bcsr_spmm_nnz(bd0[0], bd1[0], tiles[0], Cb, gr)
             return jax.lax.psum(y, axis_name=axis)
         return run
 
-    run = _spmd_runner(
-        "bcsr_spmm_nnz", mesh, axis, (gr,),
+    run, args = _spmd_runner(
+        "bcsr_spmm_nnz", mesh, axis, (gr,), specs,
         (a["bdim0"], a["bdim1"], a["vals"], C_blk), build)
 
     def call():
-        y = np.asarray(run(jnp.asarray(a["bdim0"]), jnp.asarray(a["bdim1"]),
-                           jnp.asarray(a["vals"]), jnp.asarray(C_blk)))
-        return y[:n]
+        return np.asarray(run(*args))[:n]
 
+    call.placed = args
     return call
 
 
@@ -462,29 +453,28 @@ def bcsr_sddmm_rows_spmd(kernel: LoweredKernel, mesh: Mesh, axis: str = "x"):
     D_blk = pack_mat_inner_blocks(np.asarray(D.arrays["vals"]),
                                   int(B.meta["grid_cols"]), bc)
 
+    specs = (P(axis), P(axis), P(axis), P(axis), P())
+
     def build():
-        @functools.partial(
-            shard_map, mesh=mesh,
-            in_specs=(P(axis), P(axis), P(axis), P(axis), P()),
-            out_specs=P(axis))
+        @functools.partial(jax.shard_map, mesh=mesh, in_specs=specs,
+                           out_specs=P(axis))
         def run(pos, crd, tiles, Cl, Db):
             brow = K.rows_from_pos(pos[0], crd[0].shape[0])
             return K.leaf_bcsr_sddmm(brow, crd[0], tiles[0], Cl[0],
                                      Db)[None]
         return run
 
-    run = _spmd_runner(
-        "bcsr_sddmm_rows", mesh, axis, (),
+    run, args = _spmd_runner(
+        "bcsr_sddmm_rows", mesh, axis, (), specs,
         (a["pos1"], a["crd1"], a["vals"], C_blk, D_blk), build)
 
     def call():
-        out_tiles = np.asarray(run(
-            jnp.asarray(a["pos1"]), jnp.asarray(a["crd1"]),
-            jnp.asarray(a["vals"]), jnp.asarray(C_blk), jnp.asarray(D_blk)))
+        out_tiles = np.asarray(run(*args))
         total_blocks = int(Bt.levels[1].nnz or 0)
         return _assemble_vals(total_blocks, out_tiles, a,
                               kernel.plans[Bt.name].vals_bounds)
 
+    call.placed = args
     return call
 
 
@@ -504,27 +494,26 @@ def bcsr_sddmm_nnz_spmd(kernel: LoweredKernel, mesh: Mesh, axis: str = "x"):
     D_blk = pack_mat_inner_blocks(np.asarray(D.arrays["vals"]),
                                   int(B.meta["grid_cols"]), bc)
 
+    specs = (P(axis), P(axis), P(axis), P(), P())
+
     def build():
-        @functools.partial(
-            shard_map, mesh=mesh,
-            in_specs=(P(axis), P(axis), P(axis), P(), P()),
-            out_specs=P(axis))
+        @functools.partial(jax.shard_map, mesh=mesh, in_specs=specs,
+                           out_specs=P(axis))
         def run(bd0, bd1, tiles, Cb, Db):
             return K.leaf_bcsr_sddmm(bd0[0], bd1[0], tiles[0], Cb, Db)[None]
         return run
 
-    run = _spmd_runner(
-        "bcsr_sddmm_nnz", mesh, axis, (),
+    run, args = _spmd_runner(
+        "bcsr_sddmm_nnz", mesh, axis, (), specs,
         (a["bdim0"], a["bdim1"], a["vals"], C_blk, D_blk), build)
 
     def call():
-        out_tiles = np.asarray(run(
-            jnp.asarray(a["bdim0"]), jnp.asarray(a["bdim1"]),
-            jnp.asarray(a["vals"]), jnp.asarray(C_blk), jnp.asarray(D_blk)))
+        out_tiles = np.asarray(run(*args))
         total_blocks = int(Bt.levels[1].nnz or 0)
         return _assemble_vals(total_blocks, out_tiles, a,
                               kernel.plans[Bt.name].vals_bounds)
 
+    call.placed = args
     return call
 
 
@@ -569,29 +558,28 @@ def spmm_grid_rows_spmd(kernel: LoweredKernel, mesh: Mesh, axis: str = "x"):
     crd = _grid_reshape(a["crd1"], P_, Q_)
     vals = _grid_reshape(a["vals"], P_, Q_)
     Cw = C.arrays["vals"]                       # (Q, max_kw, J)
+    specs = (P(ax, ay), P(ax, ay), P(ax, ay), P(ay))
 
     def build():
-        @functools.partial(
-            shard_map, mesh=mesh,
-            in_specs=(P(ax, ay), P(ax, ay), P(ax, ay), P(ay)),
-            out_specs=P(ax))
+        @functools.partial(jax.shard_map, mesh=mesh, in_specs=specs,
+                           out_specs=P(ax))
         def run(pos, crd, vals, Cw):
             y = K.leaf_spmm_rows(pos[0, 0], crd[0, 0], vals[0, 0], Cw[0])
             return jax.lax.psum(y, axis_name=ay)[None]
         return run
 
-    run = _spmd_runner("spmm_grid_rows", mesh, (ax, ay), (),
-                       (pos, crd, vals, Cw), build)
+    run, args = _spmd_runner("spmm_grid_rows", mesh, (ax, ay), (), specs,
+                             (pos, crd, vals, Cw), build)
 
     def call():
-        yb = np.asarray(run(jnp.asarray(pos), jnp.asarray(crd),
-                            jnp.asarray(vals), jnp.asarray(Cw)))
+        yb = np.asarray(run(*args))
         out = np.zeros((n, J), np.float32)
         rs, cnt = np.asarray(a["row_start"]), np.asarray(a["row_count"])
         for p in range(yb.shape[0]):
             out[rs[p]: rs[p] + cnt[p]] = yb[p, : cnt[p]]
         return out
 
+    call.placed = args
     return call
 
 
@@ -606,29 +594,28 @@ def spmv_grid_rows_spmd(kernel: LoweredKernel, mesh: Mesh, axis: str = "x"):
     crd = _grid_reshape(a["crd1"], P_, Q_)
     vals = _grid_reshape(a["vals"], P_, Q_)
     cw = c.arrays["vals"]                       # (Q, max_kw)
+    specs = (P(ax, ay), P(ax, ay), P(ax, ay), P(ay))
 
     def build():
-        @functools.partial(
-            shard_map, mesh=mesh,
-            in_specs=(P(ax, ay), P(ax, ay), P(ax, ay), P(ay)),
-            out_specs=P(ax))
+        @functools.partial(jax.shard_map, mesh=mesh, in_specs=specs,
+                           out_specs=P(ax))
         def run(pos, crd, vals, cw):
             y = K.leaf_spmv_rows(pos[0, 0], crd[0, 0], vals[0, 0], cw[0])
             return jax.lax.psum(y, axis_name=ay)[None]
         return run
 
-    run = _spmd_runner("spmv_grid_rows", mesh, (ax, ay), (),
-                       (pos, crd, vals, cw), build)
+    run, args = _spmd_runner("spmv_grid_rows", mesh, (ax, ay), (), specs,
+                             (pos, crd, vals, cw), build)
 
     def call():
-        yb = np.asarray(run(jnp.asarray(pos), jnp.asarray(crd),
-                            jnp.asarray(vals), jnp.asarray(cw)))
+        yb = np.asarray(run(*args))
         out = np.zeros(n, np.float32)
         rs, cnt = np.asarray(a["row_start"]), np.asarray(a["row_count"])
         for p in range(yb.shape[0]):
             out[rs[p]: rs[p] + cnt[p]] = yb[p, : cnt[p]]
         return out
 
+    call.placed = args
     return call
 
 
@@ -649,25 +636,22 @@ def sddmm_grid_rows_spmd(kernel: LoweredKernel, mesh: Mesh, axis: str = "x"):
     vals = _grid_reshape(a["vals"], P_, Q_)
     Cw = C.arrays["vals"]                       # (P, max_rw, K)
     Dw = D.arrays["vals"]                       # (Q, K, max_mw)
+    specs = (P(ax, ay), P(ax, ay), P(ax, ay), P(ax), P(ay))
 
     def build():
-        @functools.partial(
-            shard_map, mesh=mesh,
-            in_specs=(P(ax, ay), P(ax, ay), P(ax, ay), P(ax), P(ay)),
-            out_specs=P(ax, ay))
+        @functools.partial(jax.shard_map, mesh=mesh, in_specs=specs,
+                           out_specs=P(ax, ay))
         def run(pos, crd, vals, Cw, Dw):
             out = K.leaf_sddmm_rows(pos[0, 0], crd[0, 0], vals[0, 0],
                                     Cw[0], Dw[0])
             return out[None, None]
         return run
 
-    run = _spmd_runner("sddmm_grid_rows", mesh, (ax, ay), (),
-                       (pos, crd, vals, Cw, Dw), build)
+    run, args = _spmd_runner("sddmm_grid_rows", mesh, (ax, ay), (), specs,
+                             (pos, crd, vals, Cw, Dw), build)
 
     def call():
-        out_vals = np.asarray(run(
-            jnp.asarray(pos), jnp.asarray(crd), jnp.asarray(vals),
-            jnp.asarray(Cw), jnp.asarray(Dw)))    # (P, Q, max_tnnz)
+        out_vals = np.asarray(run(*args))       # (P, Q, max_tnnz)
         flat = np.zeros(Bt.nnz, np.float32)
         vi = np.asarray(a["val_idx"]).reshape(P_, Q_, -1)
         cnt = np.asarray(a["nnz_count"]).reshape(P_, Q_)
@@ -677,6 +661,7 @@ def sddmm_grid_rows_spmd(kernel: LoweredKernel, mesh: Mesh, axis: str = "x"):
                 flat[vi[p, q, :k]] = out_vals[p, q, :k]
         return flat
 
+    call.placed = args
     return call
 
 
@@ -698,30 +683,29 @@ def bcsr_spmm_grid_rows_spmd(kernel: LoweredKernel, mesh: Mesh,
     Cw = pack_window_mat_row_blocks(np.asarray(C.arrays["vals"]),
                                     int(a["bcol_count"].max()),
                                     int(B.meta["bc"]))
+    specs = (P(ax, ay), P(ax, ay), P(ax, ay), P(ay))
 
     def build():
-        @functools.partial(
-            shard_map, mesh=mesh,
-            in_specs=(P(ax, ay), P(ax, ay), P(ax, ay), P(ay)),
-            out_specs=P(ax))
+        @functools.partial(jax.shard_map, mesh=mesh, in_specs=specs,
+                           out_specs=P(ax))
         def run(pos, crd, tiles, Cw):
             y = K.leaf_bcsr_spmm_rows(pos[0, 0], crd[0, 0], tiles[0, 0],
                                       Cw[0])
             return jax.lax.psum(y, axis_name=ay)[None]
         return run
 
-    run = _spmd_runner("bcsr_spmm_grid_rows", mesh, (ax, ay), (),
-                       (pos, crd, vals, Cw), build)
+    run, args = _spmd_runner("bcsr_spmm_grid_rows", mesh, (ax, ay), (),
+                             specs, (pos, crd, vals, Cw), build)
 
     def call():
-        yb = np.asarray(run(jnp.asarray(pos), jnp.asarray(crd),
-                            jnp.asarray(vals), jnp.asarray(Cw)))
+        yb = np.asarray(run(*args))
         out = np.zeros((n, J), np.float32)
         rs, cnt = np.asarray(a["row_start"]), np.asarray(a["row_count"])
         for p in range(yb.shape[0]):
             out[rs[p]: rs[p] + cnt[p]] = yb[p, : cnt[p]]
         return out
 
+    call.placed = args
     return call
 
 
@@ -743,23 +727,21 @@ def spmm_grid_rep_spmd(kernel: LoweredKernel, mesh: Mesh, axis: str = "x"):
     crd = _grid_reshape(a["crd1"], P_, Q_)
     vals = _grid_reshape(a["vals"], P_, Q_)
     Cw = C.arrays["vals"]                       # (Q, R, max_kw, max_jw)
+    specs = (P(ax, ay), P(ax, ay), P(ax, ay), P(ay, az))
 
     def build():
-        @functools.partial(
-            shard_map, mesh=mesh,
-            in_specs=(P(ax, ay), P(ax, ay), P(ax, ay), P(ay, az)),
-            out_specs=P(ax, az))
+        @functools.partial(jax.shard_map, mesh=mesh, in_specs=specs,
+                           out_specs=P(ax, az))
         def run(pos, crd, vals, Cw):
             y = K.leaf_spmm_rows(pos[0, 0], crd[0, 0], vals[0, 0], Cw[0, 0])
             return jax.lax.psum(y, axis_name=ay)[None, None]
         return run
 
-    run = _spmd_runner("spmm_grid_rep_rows", mesh, (ax, ay, az), (),
-                       (pos, crd, vals, Cw), build)
+    run, args = _spmd_runner("spmm_grid_rep_rows", mesh, (ax, ay, az), (),
+                             specs, (pos, crd, vals, Cw), build)
 
     def call():
-        yb = np.asarray(run(jnp.asarray(pos), jnp.asarray(crd),
-                            jnp.asarray(vals), jnp.asarray(Cw)))
+        yb = np.asarray(run(*args))
         out = np.zeros((n, J), np.float32)
         rs, cnt = np.asarray(a["row_start"]), np.asarray(a["row_count"])
         cs = np.asarray(C.arrays["col_start"])
@@ -770,6 +752,7 @@ def spmm_grid_rep_spmd(kernel: LoweredKernel, mesh: Mesh, axis: str = "x"):
                     yb[p, r, : cnt[p], : cw[r]]
         return out
 
+    call.placed = args
     return call
 
 
@@ -792,25 +775,22 @@ def sddmm_grid_rep_spmd(kernel: LoweredKernel, mesh: Mesh, axis: str = "x"):
     vals = _grid_reshape(a["vals"], P_, Q_)
     Cw = C.arrays["vals"]                       # (P, R, max_rw, max_kw)
     Dw = D.arrays["vals"]                       # (R, Q, max_kw, max_mw)
+    specs = (P(ax, ay), P(ax, ay), P(ax, ay), P(ax, az), P(az, ay))
 
     def build():
-        @functools.partial(
-            shard_map, mesh=mesh,
-            in_specs=(P(ax, ay), P(ax, ay), P(ax, ay), P(ax, az), P(az, ay)),
-            out_specs=P(ax, ay))
+        @functools.partial(jax.shard_map, mesh=mesh, in_specs=specs,
+                           out_specs=P(ax, ay))
         def run(pos, crd, vals, Cw, Dw):
             out = K.leaf_sddmm_rows(pos[0, 0], crd[0, 0], vals[0, 0],
                                     Cw[0, 0], Dw[0, 0])
             return jax.lax.psum(out, axis_name=az)[None, None]
         return run
 
-    run = _spmd_runner("sddmm_grid_rep_rows", mesh, (ax, ay, az), (),
-                       (pos, crd, vals, Cw, Dw), build)
+    run, args = _spmd_runner("sddmm_grid_rep_rows", mesh, (ax, ay, az), (),
+                             specs, (pos, crd, vals, Cw, Dw), build)
 
     def call():
-        out_vals = np.asarray(run(
-            jnp.asarray(pos), jnp.asarray(crd), jnp.asarray(vals),
-            jnp.asarray(Cw), jnp.asarray(Dw)))    # (P, Q, max_tnnz)
+        out_vals = np.asarray(run(*args))       # (P, Q, max_tnnz)
         flat = np.zeros(Bt.nnz, np.float32)
         vi = np.asarray(a["val_idx"]).reshape(P_, Q_, -1)
         cnt = np.asarray(a["nnz_count"]).reshape(P_, Q_)
@@ -820,6 +800,7 @@ def sddmm_grid_rep_spmd(kernel: LoweredKernel, mesh: Mesh, axis: str = "x"):
                 flat[vi[p, q, :k]] = out_vals[p, q, :k]
         return flat
 
+    call.placed = args
     return call
 
 
@@ -845,32 +826,30 @@ def spmttkrp_grid3_spmd(kernel: LoweredKernel, mesh: Mesh, axis: str = "x"):
     vals = brick(a["vals"])
     Cw = C.arrays["vals"]                       # (Q, max_jw, L)
     Dw = D.arrays["vals"]                       # (R, max_kw, L)
+    specs = (P(ax, ay, az), P(ax, ay, az), P(ax, ay, az), P(ax, ay, az),
+             P(ay), P(az))
 
     def build():
-        @functools.partial(
-            shard_map, mesh=mesh,
-            in_specs=(P(ax, ay, az), P(ax, ay, az), P(ax, ay, az),
-                      P(ax, ay, az), P(ay), P(az)),
-            out_specs=P(ax))
+        @functools.partial(jax.shard_map, mesh=mesh, in_specs=specs,
+                           out_specs=P(ax))
         def run(d0, d1, d2, vals, Cw, Dw):
             y = K.leaf_spmttkrp_nnz(d0[0, 0, 0], d1[0, 0, 0], d2[0, 0, 0],
                                     vals[0, 0, 0], Cw[0], Dw[0], max_rows)
             return jax.lax.psum(y, axis_name=(ay, az))[None]
         return run
 
-    run = _spmd_runner("spmttkrp_grid3_rows", mesh, (ax, ay, az), (),
-                       (d0, d1, d2, vals, Cw, Dw), build)
+    run, args = _spmd_runner("spmttkrp_grid3_rows", mesh, (ax, ay, az), (),
+                             specs, (d0, d1, d2, vals, Cw, Dw), build)
 
     def call():
-        yb = np.asarray(run(jnp.asarray(d0), jnp.asarray(d1),
-                            jnp.asarray(d2), jnp.asarray(vals),
-                            jnp.asarray(Cw), jnp.asarray(Dw)))
+        yb = np.asarray(run(*args))
         out = np.zeros(out_shape, np.float32)
         rs, cnt = np.asarray(a["row_start"]), np.asarray(a["row_count"])
         for p in range(yb.shape[0]):
             out[rs[p]: rs[p] + cnt[p]] = yb[p, : cnt[p]]
         return out
 
+    call.placed = args
     return call
 
 
@@ -900,6 +879,10 @@ SPMD_BUILDERS: Dict[str, Callable] = {
 def to_spmd(kernel: LoweredKernel, mesh: Mesh = None, axis: str = "x",
             overlap: bool = False, overlap_chunks: int = 2):
     """SPMD executor for a lowered kernel, when a builder exists.
+
+    The returned ``() -> output`` callable runs on shard arrays placed once,
+    here, under the builder's in_specs; ``call.placed`` holds them (what
+    each device stores is ``placed[i].addressable_shards``).
 
     ``mesh`` is data, not trace state: pass nothing to realize the
     kernel's own Machine, a ``jax.sharding.Mesh``, or a ``Machine``
@@ -1309,12 +1292,11 @@ def spmm_grid_rows_overlap_spmd(kernel: LoweredKernel, mesh: Mesh,
     vals = _grid_reshape(a["vals"], P_, Q_)
     Cw = C.arrays["vals"]                       # (Q, max_kw, J)
     bounds = tuple(_chunk_bounds(int(J), chunks))
+    specs = (P(ax, ay), P(ax, ay), P(ax, ay), P(ay))
 
     def build():
-        @functools.partial(
-            shard_map, mesh=mesh,
-            in_specs=(P(ax, ay), P(ax, ay), P(ax, ay), P(ay)),
-            out_specs=P(ax))
+        @functools.partial(jax.shard_map, mesh=mesh, in_specs=specs,
+                           out_specs=P(ax))
         def run(pos, crd, vals, Cw):
             outs = []
             for c0, c1 in bounds:
@@ -1324,18 +1306,18 @@ def spmm_grid_rows_overlap_spmd(kernel: LoweredKernel, mesh: Mesh,
             return jnp.concatenate(outs, axis=-1)[None]
         return run
 
-    run = _spmd_runner("spmm_grid_rows_overlap", mesh, (ax, ay), (bounds,),
-                       (pos, crd, vals, Cw), build)
+    run, args = _spmd_runner("spmm_grid_rows_overlap", mesh, (ax, ay),
+                             (bounds,), specs, (pos, crd, vals, Cw), build)
 
     def call():
-        yb = np.asarray(run(jnp.asarray(pos), jnp.asarray(crd),
-                            jnp.asarray(vals), jnp.asarray(Cw)))
+        yb = np.asarray(run(*args))
         out = np.zeros((n, J), np.float32)
         rs, cnt = np.asarray(a["row_start"]), np.asarray(a["row_count"])
         for p in range(yb.shape[0]):
             out[rs[p]: rs[p] + cnt[p]] = yb[p, : cnt[p]]
         return out
 
+    call.placed = args
     return call
 
 

@@ -13,9 +13,8 @@ from typing import Optional, Sequence, Tuple
 
 import jax
 import numpy as np
-from jax.sharding import Mesh
+from jax.sharding import AxisType, Mesh
 
-from ..compat import make_mesh_compat
 from ..core.tdn import Machine
 
 
@@ -29,7 +28,8 @@ def make_mesh(shape: Sequence[int], axes: Sequence[str]) -> Mesh:
             f"exceeds the {have} visible device(s); shrink the grid or "
             f"expose more devices (e.g. XLA_FLAGS="
             f"--xla_force_host_platform_device_count={want} on CPU)")
-    return make_mesh_compat(shape, axes)
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def machine_to_mesh(machine: Machine) -> Mesh:

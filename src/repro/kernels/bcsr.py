@@ -67,7 +67,7 @@ def _bcsr_spmv_kernel(brows_ref, crd_ref, bvals_ref, c_ref, out_ref, *,
 
 def bcsr_spmv(brows_rel: jax.Array, crd: jax.Array, bvals: jax.Array,
               c_blk: jax.Array, *, block_R: int = 8, block_nb: int = 16,
-              interpret: bool = True) -> jax.Array:
+              interpret: bool) -> jax.Array:
     """Returns y of shape (n_groups * block_R * br,).
 
     Inputs are ``layout.bcsr_ell_pack`` arrays; ``c_blk`` is the dense
@@ -117,7 +117,7 @@ def _bcsr_spmm_kernel(brows_ref, crd_ref, bvals_ref, c_ref, out_ref, *,
 
 def bcsr_spmm(brows_rel: jax.Array, crd: jax.Array, bvals: jax.Array,
               C_blk: jax.Array, *, block_R: int = 8, block_nb: int = 16,
-              interpret: bool = True) -> jax.Array:
+              interpret: bool) -> jax.Array:
     """Returns Y of shape (n_groups * block_R * br, J). ``C_blk`` is the
     dense operand in row blocks (grid_cols, bc, J); J stays VMEM-resident
     (j-block with multiple calls for very wide J, see spmm.py)."""
@@ -159,7 +159,7 @@ def _bcsr_sddmm_kernel(brow_ref, bcol_ref, bvals_ref, c_ref, d_ref, out_ref):
 
 def bcsr_sddmm(brow: jax.Array, bcol: jax.Array, bvals: jax.Array,
                C_blk: jax.Array, D_blk: jax.Array, *, block_nb: int = 16,
-               interpret: bool = True) -> jax.Array:
+               interpret: bool) -> jax.Array:
     """Returns out tiles (n_blocks_padded, br, bc) aligned with the stored
     block positions. ``brow``/``bcol`` are GLOBAL block coordinates
     (clipped for padding slots — their tiles are zero); ``C_blk``
@@ -212,7 +212,7 @@ def _bcsr_spadd3_kernel(r1, c1, v1, r2, c2, v2, r3, c3, v3, out_ref, *,
 
 
 def bcsr_spadd3(packed1, packed2, packed3, *, n_rows: int, n_cols: int,
-                block_R: int = 8, interpret: bool = True) -> jax.Array:
+                block_R: int = 8, interpret: bool) -> jax.Array:
     """Fused three-way blocked add into dense rows.
 
     Each ``packed`` is a ``layout.bcsr_ell_pack`` result over the SAME

@@ -81,7 +81,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
 
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                     block_q: int = 128, block_k: int = 128,
-                    interpret: bool = True) -> jax.Array:
+                    interpret: bool) -> jax.Array:
     """Causal GQA flash attention.
 
     q: (B, S, H, hd); k, v: (B, S, Hkv, hd) with H = G·Hkv.

@@ -4,10 +4,12 @@ Every op takes ``impl``:
   - ``"xla"``    — the pure-jnp leaves from ref.py, jitted. Fast on this
                    CPU container; also the lowering used inside pjit'd model
                    code (XLA ops shard/fuse under GSPMD).
-  - ``"pallas"`` — the TPU Pallas kernels, run with ``interpret=True`` off
-                   TPU. This is the production TPU path; interpret mode
-                   exists to validate kernel logic on CPU (per-kernel
-                   allclose tests sweep shapes/dtypes against ref.py).
+  - ``"pallas"`` — the Pallas kernels. The caller states the mode with
+                   ``interpret``: ``True`` runs the Pallas interpreter (how
+                   the per-kernel allclose tests validate kernel logic on
+                   CPU against ref.py), ``False`` compiles for the TPU.
+                   There is no default, so no caller lands in the
+                   interpreter on a chip without saying so.
 
 Layout packing (CSR → row-block ELL / padded COO) happens here so callers
 hand over plain CSR/COO shard arrays.
@@ -15,7 +17,7 @@ hand over plain CSR/COO shard arrays.
 from __future__ import annotations
 
 from functools import partial
-from typing import Tuple
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -32,11 +34,13 @@ from .spmm import spmm_ell
 from .spmttkrp import spmttkrp_ell
 from .spmv import spmv_coo_phase1, spmv_ell
 
-def _interpret() -> bool:
-    """Pallas interpret mode everywhere but real TPUs. Evaluated lazily so
-    importing this module never initializes the JAX device topology (the
-    dry-run must set XLA_FLAGS first)."""
-    return jax.default_backend() != "tpu"
+
+def _pallas_interpret(interpret: Optional[bool]) -> bool:
+    """The ``interpret`` flag every ``impl="pallas"`` call must state."""
+    if interpret is None:
+        raise TypeError("impl='pallas' needs interpret=True (Pallas "
+                        "interpreter) or interpret=False (compiled kernel)")
+    return interpret
 
 
 # ---------------------------------------------------------------------------
@@ -44,7 +48,8 @@ def _interpret() -> bool:
 # ---------------------------------------------------------------------------
 
 def spmv(pos, crd, vals, c, impl: str = "xla",
-         block_r: int = 8, block_n: int = 128):
+         block_r: int = 8, block_n: int = 128,
+         interpret: Optional[bool] = None):
     """y(n,) = CSR(pos, crd, vals) @ c."""
     if impl == "xla":
         return jax.jit(ref.leaf_spmv_rows)(jnp.asarray(pos), jnp.asarray(crd),
@@ -53,12 +58,13 @@ def spmv(pos, crd, vals, c, impl: str = "xla",
                        block_r=block_r, block_n=block_n)
     y = spmv_ell(jnp.asarray(blocks.rows_rel), jnp.asarray(blocks.crd),
                  jnp.asarray(blocks.vals), jnp.asarray(c),
-                 block_r=block_r, block_n=block_n, interpret=_interpret())
+                 block_r=block_r, block_n=block_n,
+                 interpret=_pallas_interpret(interpret))
     return y[: pos.shape[0] - 1]
 
 
 def spmv_nnz(rows, cols, vals, c, n_rows: int, impl: str = "xla",
-             block_n: int = 128):
+             block_n: int = 128, interpret: Optional[bool] = None):
     """y(n,) from sorted COO — the non-zero strategy leaf + merge."""
     if impl == "xla":
         f = jax.jit(partial(ref.leaf_spmv_nnz, max_rows=n_rows))
@@ -68,7 +74,8 @@ def spmv_nnz(rows, cols, vals, c, n_rows: int, impl: str = "xla",
                                 np.asarray(vals), block_n=block_n)
     psum, prow = spmv_coo_phase1(jnp.asarray(r), jnp.asarray(cc),
                                  jnp.asarray(v), jnp.asarray(c),
-                                 block_n=block_n, interpret=_interpret())
+                                 block_n=block_n,
+                                 interpret=_pallas_interpret(interpret))
     return jax.ops.segment_sum(psum.ravel(), prow.ravel(),
                                num_segments=n_rows)
 
@@ -78,7 +85,8 @@ def spmv_nnz(rows, cols, vals, c, n_rows: int, impl: str = "xla",
 # ---------------------------------------------------------------------------
 
 def spmm(pos, crd, vals, C, impl: str = "xla",
-         block_r: int = 8, block_n: int = 128, block_j: int = 128):
+         block_r: int = 8, block_n: int = 128, block_j: int = 128,
+         interpret: Optional[bool] = None):
     """Y(n, J) = CSR @ C(K, J)."""
     if impl == "xla":
         return jax.jit(ref.leaf_spmm_rows)(jnp.asarray(pos), jnp.asarray(crd),
@@ -88,7 +96,7 @@ def spmm(pos, crd, vals, C, impl: str = "xla",
     y = spmm_ell(jnp.asarray(blocks.rows_rel), jnp.asarray(blocks.crd),
                  jnp.asarray(blocks.vals), jnp.asarray(C),
                  block_r=block_r, block_n=block_n, block_j=block_j,
-                 interpret=_interpret())
+                 interpret=_pallas_interpret(interpret))
     return y[: pos.shape[0] - 1]
 
 
@@ -99,7 +107,8 @@ def spmm(pos, crd, vals, C, impl: str = "xla",
 # ---------------------------------------------------------------------------
 
 def spmv_bcsr(pos, crd, tiles, c, impl: str = "xla",
-              block_R=None, block_nb=None):
+              block_R=None, block_nb=None,
+              interpret: Optional[bool] = None):
     """y(grid_rows * br,) = BCSR(pos, crd, tiles) @ c — slice to n_rows.
 
     ``block_R``/``block_nb`` default to the autotuned group shape
@@ -119,12 +128,13 @@ def spmv_bcsr(pos, crd, tiles, c, impl: str = "xla",
     y = bcsr_spmv(jnp.asarray(blocks.brows_rel), jnp.asarray(blocks.crd),
                   jnp.asarray(blocks.vals), jnp.asarray(c_blk),
                   block_R=block_R, block_nb=block_nb,
-                  interpret=_interpret())
+                  interpret=_pallas_interpret(interpret))
     return y[: (pos.shape[0] - 1) * tiles.shape[1]]
 
 
 def spmm_bcsr(pos, crd, tiles, C, impl: str = "xla",
-              block_R=None, block_nb=None):
+              block_R=None, block_nb=None,
+              interpret: Optional[bool] = None):
     """Y(grid_rows * br, J) = BCSR @ C(K, J) — slice to n_rows.
 
     ``block_R``/``block_nb`` default to the autotuned group shape
@@ -145,12 +155,12 @@ def spmm_bcsr(pos, crd, tiles, C, impl: str = "xla",
     y = bcsr_spmm(jnp.asarray(blocks.brows_rel), jnp.asarray(blocks.crd),
                   jnp.asarray(blocks.vals), jnp.asarray(C_blk),
                   block_R=block_R, block_nb=block_nb,
-                  interpret=_interpret())
+                  interpret=_pallas_interpret(interpret))
     return y[: (pos.shape[0] - 1) * tiles.shape[1]]
 
 
 def sddmm_bcsr(brow, bcol, tiles, C, D, impl: str = "xla",
-               block_nb: int = 16):
+               block_nb: int = 16, interpret: Optional[bool] = None):
     """out tiles (nb, br, bc) = tiles ⊙ sampled C(n,K) @ D(K,m) blocks."""
     tiles = np.asarray(tiles)
     br, bc = tiles.shape[1], tiles.shape[2]
@@ -171,12 +181,13 @@ def sddmm_bcsr(brow, bcol, tiles, C, D, impl: str = "xla",
     out = bcsr_sddmm(jnp.asarray(bpad), jnp.asarray(cpad),
                      jnp.asarray(tpad), jnp.asarray(C_blk),
                      jnp.asarray(D_blk), block_nb=block_nb,
-                     interpret=_interpret())
+                     interpret=_pallas_interpret(interpret))
     return out[:nb]
 
 
 def spadd3_bcsr_dense(bcsr1, bcsr2, bcsr3, n_rows: int, n_cols: int,
-                      impl: str = "pallas", block_R=None):
+                      impl: str = "pallas", block_R=None,
+                      interpret: Optional[bool] = None):
     """Dense(n, m) = B + C + D from three blocked (pos, crd, tiles)
     triples sharing one block shape — the fused blocked add.
 
@@ -197,14 +208,16 @@ def spadd3_bcsr_dense(bcsr1, bcsr2, bcsr3, n_rows: int, n_cols: int,
                             block_R=block_R)
               for (p, c, t) in (bcsr1, bcsr2, bcsr3)]
     return bcsr_spadd3(*packed, n_rows=n_rows, n_cols=n_cols,
-                       block_R=block_R, interpret=_interpret())
+                       block_R=block_R,
+                       interpret=_pallas_interpret(interpret))
 
 
 # ---------------------------------------------------------------------------
 # SDDMM
 # ---------------------------------------------------------------------------
 
-def sddmm(rows, cols, vals, C, D, impl: str = "xla", block_n: int = 128):
+def sddmm(rows, cols, vals, C, D, impl: str = "xla", block_n: int = 128,
+          interpret: Optional[bool] = None):
     """out_vals(nnz,) = vals ⊙ (C @ D) sampled at (rows, cols)."""
     if impl == "xla":
         return jax.jit(ref.leaf_sddmm_nnz)(
@@ -215,7 +228,7 @@ def sddmm(rows, cols, vals, C, D, impl: str = "xla", block_n: int = 128):
                                 np.asarray(vals), block_n=block_n)
     out = sddmm_coo(jnp.asarray(r), jnp.asarray(cc), jnp.asarray(v),
                     jnp.asarray(C), jnp.asarray(D), block_n=block_n,
-                    interpret=_interpret())
+                    interpret=_pallas_interpret(interpret))
     return out[:nnz]
 
 
@@ -224,7 +237,8 @@ def sddmm(rows, cols, vals, C, D, impl: str = "xla", block_n: int = 128):
 # ---------------------------------------------------------------------------
 
 def spadd3_dense(csr1, csr2, csr3, n_rows: int, n_cols: int,
-                 impl: str = "xla", block_r: int = 8, block_m: int = 128):
+                 impl: str = "xla", block_r: int = 8, block_m: int = 128,
+                 interpret: Optional[bool] = None):
     """Dense(n, m) = B + C + D from three CSR triples (pos, crd, vals)."""
     if impl == "xla":
         f = jax.jit(partial(ref.leaf_spadd3_dense_rows, n_cols=n_cols))
@@ -237,7 +251,7 @@ def spadd3_dense(csr1, csr2, csr3, n_rows: int, n_cols: int,
                    jnp.asarray(blocks.vals)]
     return spadd3_dense_tiles(*packed, n_rows=n_rows, n_cols=n_cols,
                               block_r=block_r, block_m=block_m,
-                              interpret=_interpret())
+                              interpret=_pallas_interpret(interpret))
 
 
 # ---------------------------------------------------------------------------
@@ -245,7 +259,8 @@ def spadd3_dense(csr1, csr2, csr3, n_rows: int, n_cols: int,
 # ---------------------------------------------------------------------------
 
 def spttv(pos1, crd1, pos2, crd2, vals, c, impl: str = "xla",
-          block_r: int = 8, block_n: int = 128):
+          block_r: int = 8, block_n: int = 128,
+          interpret: Optional[bool] = None):
     """out_vals aligned with B's (i,j) positions (pattern-preserving)."""
     if impl == "xla":
         return jax.jit(ref.leaf_spttv_rows)(
@@ -256,7 +271,8 @@ def spttv(pos1, crd1, pos2, crd2, vals, c, impl: str = "xla",
                        block_r=block_r, block_n=block_n)
     out = spmv_ell(jnp.asarray(blocks.rows_rel), jnp.asarray(blocks.crd),
                    jnp.asarray(blocks.vals), jnp.asarray(c),
-                   block_r=block_r, block_n=block_n, interpret=_interpret())
+                   block_r=block_r, block_n=block_n,
+                   interpret=_pallas_interpret(interpret))
     return out[:n_ij]
 
 
@@ -265,7 +281,8 @@ def spttv(pos1, crd1, pos2, crd2, vals, c, impl: str = "xla",
 # ---------------------------------------------------------------------------
 
 def spmttkrp(pos1, crd1, pos2, crd2, vals, C, D, impl: str = "xla",
-             block_r: int = 8, block_n: int = 128):
+             block_r: int = 8, block_n: int = 128,
+             interpret: Optional[bool] = None):
     """A(n, L) = B(i,j,k)·C(j,l)·D(k,l) from a CSF shard."""
     if impl == "xla":
         return jax.jit(ref.leaf_spmttkrp_rows)(
@@ -291,5 +308,5 @@ def spmttkrp(pos1, crd1, pos2, crd2, vals, C, D, impl: str = "xla",
                        jnp.asarray(kk), jnp.asarray(blocks.vals),
                        jnp.asarray(C), jnp.asarray(D),
                        block_r=block_r, block_n=block_n,
-                       interpret=_interpret())
+                       interpret=_pallas_interpret(interpret))
     return out[:n_rows]

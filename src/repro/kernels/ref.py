@@ -224,6 +224,10 @@ def leaf_spadd3_dense_rows(pos1, crd1, v1, pos2, crd2, v2, pos3, crd3, v3,
 # blocks keep their zero padding, which multiplies away.
 # ---------------------------------------------------------------------------
 
+# The TPU's default matmul precision runs float32 operands as bf16 passes;
+# HIGHEST keeps the tile matmuls float32 on the MXU, as they are on the CPU.
+_F32 = jax.lax.Precision.HIGHEST
+
 def leaf_bcsr_spmv_rows(pos, crd, bvals, c_blk):
     """y_local(R*br,) from a blocked row shard: per stored block a
     (br, bc) @ (bc,) tile matvec, segment-summed over block-rows.
@@ -231,7 +235,7 @@ def leaf_bcsr_spmv_rows(pos, crd, bvals, c_blk):
     R = pos.shape[0] - 1
     brow = rows_from_pos(pos, crd.shape[0])
     cg = jnp.take(c_blk, crd, axis=0)                  # (NB, bc)
-    prod = jnp.einsum("nrc,nc->nr", bvals, cg)
+    prod = jnp.einsum("nrc,nc->nr", bvals, cg, precision=_F32)
     acc = jax.ops.segment_sum(prod, brow, num_segments=R)
     return acc.reshape(-1)
 
@@ -240,7 +244,7 @@ def leaf_bcsr_spmv_nnz(brow_local, bcol, bvals, c_blk, max_brows):
     """Equal-stored-block shard: block-rows already rebased to the shard's
     block-row window; padding blocks have zero tiles."""
     cg = jnp.take(c_blk, bcol, axis=0)
-    prod = jnp.einsum("nrc,nc->nr", bvals, cg)
+    prod = jnp.einsum("nrc,nc->nr", bvals, cg, precision=_F32)
     acc = jax.ops.segment_sum(prod, brow_local, num_segments=max_brows)
     return acc.reshape(-1)
 
@@ -251,14 +255,14 @@ def leaf_bcsr_spmm_rows(pos, crd, bvals, C_blk):
     R = pos.shape[0] - 1
     brow = rows_from_pos(pos, crd.shape[0])
     cg = jnp.take(C_blk, crd, axis=0)                  # (NB, bc, J)
-    prod = jnp.einsum("nrc,ncj->nrj", bvals, cg)
+    prod = jnp.einsum("nrc,ncj->nrj", bvals, cg, precision=_F32)
     acc = jax.ops.segment_sum(prod, brow, num_segments=R)
     return acc.reshape(-1, cg.shape[-1])
 
 
 def leaf_bcsr_spmm_nnz(brow_local, bcol, bvals, C_blk, max_brows):
     cg = jnp.take(C_blk, bcol, axis=0)
-    prod = jnp.einsum("nrc,ncj->nrj", bvals, cg)
+    prod = jnp.einsum("nrc,ncj->nrj", bvals, cg, precision=_F32)
     acc = jax.ops.segment_sum(prod, brow_local, num_segments=max_brows)
     return acc.reshape(-1, cg.shape[-1])
 
@@ -270,7 +274,7 @@ def leaf_bcsr_sddmm(brow, bcol, bvals, C_blk, D_blk):
     grid under nnz; ``D_blk`` (grid_cols, K, bc) column blocks."""
     Cg = jnp.take(C_blk, brow, axis=0)                 # (NB, br, K)
     Dg = jnp.take(D_blk, bcol, axis=0)                 # (NB, K, bc)
-    sampled = jnp.einsum("nrk,nkc->nrc", Cg, Dg)
+    sampled = jnp.einsum("nrk,nkc->nrc", Cg, Dg, precision=_F32)
     return bvals * sampled
 
 

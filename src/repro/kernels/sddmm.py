@@ -44,7 +44,7 @@ def _sddmm_kernel(rows_ref, cols_ref, vals_ref, c_ref, dt_ref, out_ref):
 
 def sddmm_coo(rows: jax.Array, cols: jax.Array, vals: jax.Array,
               C: jax.Array, D: jax.Array, *, block_n: int = 128,
-              interpret: bool = True) -> jax.Array:
+              interpret: bool) -> jax.Array:
     """Returns out_vals (nnz,), aligned with the COO positions.
 
     ``rows``/``cols`` may contain out-of-range sentinels for padding; their

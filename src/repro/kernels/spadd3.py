@@ -57,7 +57,7 @@ def _spadd3_kernel(r1, c1, v1, r2, c2, v2, r3, c3, v3, out_ref, *,
 def spadd3_dense_tiles(rows1, cols1, vals1, rows2, cols2, vals2,
                        rows3, cols3, vals3, *, n_rows: int, n_cols: int,
                        block_r: int = 8, block_m: int = 128,
-                       interpret: bool = True) -> jax.Array:
+                       interpret: bool) -> jax.Array:
     """Fused three-way add into dense row tiles.
 
     Each operand is given in row-block ELL form over the SAME row blocking

@@ -53,7 +53,7 @@ def _spmm_ell_kernel(rows_ref, crd_ref, vals_ref, c_ref, out_ref, *,
 
 def spmm_ell(rows_rel: jax.Array, crd: jax.Array, vals: jax.Array,
              C: jax.Array, *, block_r: int = 8, block_n: int = 128,
-             block_j: int = 128, interpret: bool = True) -> jax.Array:
+             block_j: int = 128, interpret: bool) -> jax.Array:
     """Returns Y of shape (n_rblocks * block_r, J_padded).
 
     ELL arrays: (n_rblocks, bnnz); C: (K, J). J is padded to block_j.

@@ -70,7 +70,7 @@ def _spmttkrp_kernel(rows_ref, j_ref, k_ref, vals_ref, c_ref, d_ref, out_ref,
 def spmttkrp_ell(rows_rel: jax.Array, j: jax.Array, k: jax.Array,
                  vals: jax.Array, C: jax.Array, D: jax.Array, *,
                  block_r: int = 8, block_n: int = 128,
-                 interpret: bool = True) -> jax.Array:
+                 interpret: bool) -> jax.Array:
     """Returns A of shape (n_rblocks * block_r, L)."""
     n_rblocks, bnnz = rows_rel.shape
     L = C.shape[1]

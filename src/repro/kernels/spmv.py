@@ -71,7 +71,7 @@ def _spmv_ell_kernel(rows_ref, crd_ref, vals_ref, c_ref, out_ref, *,
 
 def spmv_ell(rows_rel: jax.Array, crd: jax.Array, vals: jax.Array,
              c: jax.Array, *, block_r: int = 8, block_n: int = 128,
-             interpret: bool = True) -> jax.Array:
+             interpret: bool) -> jax.Array:
     """Returns y of shape (n_rblocks * block_r,).
 
     Inputs are the `layout.ell_pack` arrays: (n_rblocks, bnnz) each; ``c``
@@ -125,7 +125,7 @@ def _coo_phase1_kernel(rows_ref, crd_ref, vals_ref, c_ref, psum_ref, prow_ref):
 
 def spmv_coo_phase1(rows: jax.Array, crd: jax.Array, vals: jax.Array,
                     c: jax.Array, *, block_n: int = 128,
-                    interpret: bool = True):
+                    interpret: bool):
     """Phase 1: per-block rank partial sums + rank row ids.
 
     ``rows`` must be sorted (COO order — true after a non-zero partition of

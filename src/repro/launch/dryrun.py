@@ -25,7 +25,7 @@ import jax          # noqa: E402
 
 from ..configs.base import all_archs, get_arch  # noqa: E402
 from .mesh import make_production_mesh          # noqa: E402
-from .roofline import HloAnalyzer, roofline_report  # noqa: E402
+from .roofline import V5E, roofline_report         # noqa: E402
 from . import steps as steps_mod                # noqa: E402
 
 OUT_DIR = Path(__file__).resolve().parents[3] / "experiments" / "dryrun"
@@ -66,7 +66,8 @@ def run_cell(arch_name: str, shape_name: str, multi_pod: bool,
             ca = compiled.cost_analysis() or {}
             hlo = compiled.as_text()
             rep = roofline_report(
-                hlo, model_flops_per_device=model_flops_per_device(
+                hlo, device_kind=V5E,   # the production mesh is v5e pods
+                model_flops_per_device=model_flops_per_device(
                     cfg, shape, mesh))
             rec.update({
                 "status": "ok",

@@ -6,7 +6,7 @@ initialization.
 """
 from __future__ import annotations
 
-from ..compat import make_mesh_compat
+from ..distributed.mesh import make_mesh
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -14,9 +14,9 @@ def make_production_mesh(*, multi_pod: bool = False):
     Multi-pod: (pod=2, data=16, model=16) = 512 chips."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh_compat(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_smoke_mesh():
     """1-device mesh with the production axis names (CPU tests)."""
-    return make_mesh_compat((1, 1), ("data", "model"))
+    return make_mesh((1, 1), ("data", "model"))

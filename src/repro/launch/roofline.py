@@ -18,8 +18,8 @@ multipliers:
   branch; fusions/calls recurse for FLOPs only.
 
 The compiled module is already SPMD-partitioned, so all numbers are
-PER-DEVICE. Hardware constants: TPU v5e — 197 TFLOP/s bf16, 819 GB/s HBM,
-~50 GB/s/link ICI.
+PER-DEVICE. Hardware constants come from :data:`PEAKS`, one entry per
+``jax.Device.device_kind``; a kind that is not in the table is an error.
 """
 from __future__ import annotations
 
@@ -27,10 +27,6 @@ import dataclasses
 import json
 import re
 from typing import Dict, List, Optional, Tuple
-
-PEAK_FLOPS = 197e12       # bf16 / chip
-HBM_BW = 819e9            # bytes/s / chip
-ICI_BW = 50e9             # bytes/s / link
 
 
 @dataclasses.dataclass(frozen=True)
@@ -40,9 +36,9 @@ class HardwareModel:
     :mod:`repro.core.plan_search` (which scores candidate schedules before
     any HLO exists)."""
 
-    peak_flops: float = PEAK_FLOPS
-    hbm_bw: float = HBM_BW
-    ici_bw: float = ICI_BW
+    peak_flops: float             # FLOP/s per chip
+    hbm_bw: float                 # bytes/s per chip
+    ici_bw: float                 # bytes/s per interconnect link
 
     def compute_s(self, flops: float) -> float:
         return flops / self.peak_flops
@@ -60,7 +56,26 @@ class HardwareModel:
             + self.collective_s(coll_bytes)
 
 
-DEFAULT_HW = HardwareModel()
+#: ``device_kind`` of a TPU v5e chip as JAX reports it.
+V5E = "TPU v5 lite"
+
+#: Published per-chip peaks keyed by ``jax.Device.device_kind``. Source:
+#: Google Cloud documentation, "TPU v5e": 197 TFLOP/s bf16, 16 GB of HBM
+#: at 819 GB/s, 1,600 Gbit/s of chip-to-chip interconnect (four links of
+#: 50 GB/s).
+PEAKS = {
+    V5E: HardwareModel(peak_flops=197e12, hbm_bw=819e9, ici_bw=50e9),
+}
+
+
+def hardware_model(device_kind: str) -> HardwareModel:
+    """The peaks of ``device_kind``; raises on a kind with no published
+    entry rather than reporting a share of someone else's peak."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(f"no published peaks for device kind "
+                         f"{device_kind!r}; known: {sorted(PEAKS)}") from None
 
 _DTYPE_BYTES = {
     "pred": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2, "bf16": 2, "f16": 2,
@@ -360,10 +375,11 @@ class HloAnalyzer:
         return c
 
 
-def roofline_report(hlo_text: str, *, model_flops_per_device: float = 0.0,
-                    pieces_hint: str = "",
-                    hw: HardwareModel = DEFAULT_HW) -> Dict:
-    """Per-device roofline terms from a compiled SPMD HLO module."""
+def roofline_report(hlo_text: str, *, device_kind: str,
+                    model_flops_per_device: float = 0.0) -> Dict:
+    """Per-device roofline terms from a compiled SPMD HLO module, against
+    the peaks of ``device_kind`` (raises on a kind not in :data:`PEAKS`)."""
+    hw = hardware_model(device_kind)
     an = HloAnalyzer(hlo_text)
     c = an.cost()
     compute_t = hw.compute_s(c.flops)

@@ -1,5 +1,5 @@
 """Runtime subsystems: checkpointing, elastic execution, fault harness,
-telemetry.
+telemetry, the persistent compilation cache.
 
 Submodules load lazily (PEP 562): ``core.lower`` and the other pipeline
 modules import :mod:`repro.runtime.telemetry` at module scope, and an
@@ -8,7 +8,7 @@ through it ``core``) back in while ``core.lower`` is still initializing.
 """
 import importlib
 
-__all__ = ["checkpoint", "elastic", "fault", "telemetry"]
+__all__ = ["checkpoint", "compile_cache", "elastic", "fault", "telemetry"]
 
 
 def __getattr__(name):

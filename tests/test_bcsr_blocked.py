@@ -123,18 +123,21 @@ def test_bcsr_pallas_kernels(shape, block):
     pos, crd, tiles = t.levels[1].pos, t.levels[1].crd, t.vals
     c = rng.standard_normal(m).astype(np.float32)
     for impl in ("xla", "pallas"):
-        y = np.asarray(ops.spmv_bcsr(pos, crd, tiles, c, impl=impl))[:n]
+        y = np.asarray(ops.spmv_bcsr(pos, crd, tiles, c, impl=impl,
+                                     interpret=True))[:n]
         np.testing.assert_allclose(y, dense @ c, atol=1e-3, rtol=1e-3)
     C = rng.standard_normal((m, 9)).astype(np.float32)
     for impl in ("xla", "pallas"):
-        Y = np.asarray(ops.spmm_bcsr(pos, crd, tiles, C, impl=impl))[:n]
+        Y = np.asarray(ops.spmm_bcsr(pos, crd, tiles, C, impl=impl,
+                                     interpret=True))[:n]
         np.testing.assert_allclose(Y, dense @ C, atol=1e-3, rtol=1e-3)
     Cs = rng.standard_normal((n, K)).astype(np.float32)
     Ds = rng.standard_normal((K, m)).astype(np.float32)
     bc_coords = t.block_coords()
     for impl in ("xla", "pallas"):
         out = np.asarray(ops.sddmm_bcsr(bc_coords[:, 0], bc_coords[:, 1],
-                                        tiles, Cs, Ds, impl=impl))
+                                        tiles, Cs, Ds, impl=impl,
+                                        interpret=True))
         got = Tensor("o", t.shape, t.format, t.levels, out,
                      np.float32).to_dense()
         np.testing.assert_allclose(got, dense * (Cs @ Ds), atol=1e-3,
@@ -149,7 +152,7 @@ def test_bcsr_pallas_kernels(shape, block):
         total += dd
     for impl in ("xla", "pallas"):
         got = np.asarray(ops.spadd3_bcsr_dense(*triples, n_rows=n, n_cols=m,
-                                               impl=impl))
+                                               impl=impl, interpret=True))
         np.testing.assert_allclose(got, total, atol=1e-3, rtol=1e-3)
 
 

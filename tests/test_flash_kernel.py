@@ -38,7 +38,8 @@ def test_flash_matches_oracle_f32(case):
     q = jax.random.normal(ks[0], (B, S, H, hd), jnp.float32)
     k = jax.random.normal(ks[1], (B, S, Hkv, hd), jnp.float32)
     v = jax.random.normal(ks[2], (B, S, Hkv, hd), jnp.float32)
-    got = flash_attention(q, k, v, block_q=bq, block_k=bk)
+    got = flash_attention(q, k, v, block_q=bq, block_k=bk,
+                          interpret=True)
     np.testing.assert_allclose(np.asarray(got), np.asarray(oracle(q, k, v)),
                                atol=2e-5, rtol=2e-5)
 
@@ -49,7 +50,7 @@ def test_flash_bf16():
     q = jax.random.normal(ks[0], (B, S, H, hd), jnp.bfloat16)
     k = jax.random.normal(ks[1], (B, S, Hkv, hd), jnp.bfloat16)
     v = jax.random.normal(ks[2], (B, S, Hkv, hd), jnp.bfloat16)
-    got = flash_attention(q, k, v)
+    got = flash_attention(q, k, v, interpret=True)
     exp = oracle(q.astype(jnp.float32), k.astype(jnp.float32),
                  v.astype(jnp.float32))
     np.testing.assert_allclose(np.asarray(got, np.float32), np.asarray(exp),
@@ -63,7 +64,8 @@ def test_flash_first_token_and_padding_rows():
     q = jax.random.normal(ks[0], (B, S, H, hd), jnp.float32)
     k = jax.random.normal(ks[1], (B, S, Hkv, hd), jnp.float32)
     v = jax.random.normal(ks[2], (B, S, Hkv, hd), jnp.float32)
-    got = flash_attention(q, k, v, block_q=64, block_k=64)
+    got = flash_attention(q, k, v, block_q=64, block_k=64,
+                          interpret=True)
     np.testing.assert_allclose(np.asarray(got[0, 0, 0]),
                                np.asarray(v[0, 0, 0]), atol=1e-5, rtol=1e-5)
 

@@ -28,7 +28,8 @@ def test_spmv_pallas_sweep(shape, density):
     c = rng.standard_normal(m).astype(np.float32)
     pos, crd = t.levels[1].pos, t.levels[1].crd
     ref = np.asarray(ops.spmv(pos, crd, t.vals, c, impl="xla"))
-    got = np.asarray(ops.spmv(pos, crd, t.vals, c, impl="pallas"))
+    got = np.asarray(ops.spmv(pos, crd, t.vals, c, impl="pallas",
+                              interpret=True))
     np.testing.assert_allclose(got, ref, atol=1e-4, rtol=1e-4)
     np.testing.assert_allclose(ref, dense @ c, atol=1e-3, rtol=1e-3)
 
@@ -42,7 +43,7 @@ def test_spmv_nnz_pallas_sweep(shape):
     rows = np.repeat(np.arange(n, dtype=np.int32), np.diff(pos))
     c = rng.standard_normal(m).astype(np.float32)
     got = np.asarray(ops.spmv_nnz(rows, crd, t.vals, c, n_rows=n,
-                                  impl="pallas"))
+                                  impl="pallas", interpret=True))
     np.testing.assert_allclose(got, dense @ c, atol=1e-4, rtol=1e-4)
 
 
@@ -54,7 +55,8 @@ def test_spmm_pallas_sweep(shape, j):
     t, dense = _csr(rng, n, m, 0.2, np.float32)
     C = rng.standard_normal((m, j)).astype(np.float32)
     pos, crd = t.levels[1].pos, t.levels[1].crd
-    got = np.asarray(ops.spmm(pos, crd, t.vals, C, impl="pallas"))
+    got = np.asarray(ops.spmm(pos, crd, t.vals, C, impl="pallas",
+                              interpret=True))
     np.testing.assert_allclose(got, dense @ C, atol=1e-3, rtol=1e-3)
 
 
@@ -68,7 +70,8 @@ def test_sddmm_pallas_sweep(shape, K):
     rows = np.repeat(np.arange(n, dtype=np.int32), np.diff(pos))
     C = rng.standard_normal((n, K)).astype(np.float32)
     D = rng.standard_normal((K, m)).astype(np.float32)
-    got = np.asarray(ops.sddmm(rows, crd, t.vals, C, D, impl="pallas"))
+    got = np.asarray(ops.sddmm(rows, crd, t.vals, C, D, impl="pallas",
+                               interpret=True))
     exp = t.vals * (C[rows] * D[:, crd].T).sum(1)
     np.testing.assert_allclose(got, exp, atol=1e-3, rtol=1e-3)
 
@@ -84,7 +87,7 @@ def test_spadd3_pallas_sweep(shape):
         ts.append((t.levels[1].pos, t.levels[1].crd, t.vals))
         total += dense
     got = np.asarray(ops.spadd3_dense(*ts, n_rows=n, n_cols=m,
-                                      impl="pallas"))
+                                      impl="pallas", interpret=True))
     np.testing.assert_allclose(got, total, atol=1e-4, rtol=1e-4)
 
 
@@ -98,7 +101,8 @@ def test_spttv_spmttkrp_pallas_sweep(dims):
     p1, c1 = t.levels[1].pos, t.levels[1].crd
     p2, c2 = t.levels[2].pos, t.levels[2].crd
     cv = rng.standard_normal(dims[2]).astype(np.float32)
-    tv = np.asarray(ops.spttv(p1, c1, p2, c2, t.vals, cv, impl="pallas"))
+    tv = np.asarray(ops.spttv(p1, c1, p2, c2, t.vals, cv, impl="pallas",
+                              interpret=True))
     i_of_ij = np.repeat(np.arange(dims[0]), np.diff(p1))
     got = np.zeros(dims[:2], np.float32)
     got[i_of_ij, c1] = tv
@@ -108,7 +112,7 @@ def test_spttv_spmttkrp_pallas_sweep(dims):
     C = rng.standard_normal((dims[1], L)).astype(np.float32)
     D = rng.standard_normal((dims[2], L)).astype(np.float32)
     mk = np.asarray(ops.spmttkrp(p1, c1, p2, c2, t.vals, C, D,
-                                 impl="pallas"))
+                                 impl="pallas", interpret=True))
     np.testing.assert_allclose(mk, np.einsum("ijk,jl,kl->il", dB, C, D),
                                atol=1e-3, rtol=1e-3)
 

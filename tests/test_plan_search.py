@@ -297,3 +297,39 @@ def test_auto_still_picks_nnz_on_skewed_rows_with_replication_enabled():
                         A=Tensor.zeros_dense("A", (B.shape[0], 4)), B=B, C=C)
     winner = PS.search(stmt, M4, config=MODEL_ONLY)
     assert winner is not None and winner.space == "nnz", winner.label
+
+
+def test_failed_measurement_is_recorded_on_the_candidate(monkeypatch):
+    """A candidate whose timing raises keeps an infinite measured cost and
+    carries the error into the provenance and explain(); the search still
+    returns the best candidate that did run."""
+    stmt = _spmv(_skewed_csr())
+    real_measure = PS._measure
+
+    def flaky(stmt_, point, *args, **kwargs):
+        if point.space == "nnz":
+            raise MemoryError("RESOURCE_EXHAUSTED: out of HBM")
+        return real_measure(stmt_, point, *args, **kwargs)
+
+    monkeypatch.setattr(PS, "_measure", flaky)
+    cfg = PS.SearchConfig(refine_top_k=2, measure_warmup=0, measure_iters=1)
+    monkeypatch.setattr(PS, "DEFAULT_CONFIG", cfg)
+    k = lower(stmt, M4, schedule="auto")
+    failed = [c for c in k.tuned.candidates if c["error"]]
+    assert [c["label"] for c in failed] == ["nnz/4x1"]
+    assert failed[0]["measured_s"] == float("inf")
+    assert "MemoryError: RESOURCE_EXHAUSTED" in failed[0]["error"]
+    assert k.tuned.space == "universe"          # the candidate that ran won
+    assert "FAILED measure: MemoryError" in k.explain()
+
+
+def test_peaks_are_keyed_by_device_kind():
+    """The planner plans against the v5e entry by name; a kind with no
+    published peaks raises instead of borrowing another chip's."""
+    from repro.launch import roofline
+    assert PS.PLAN_HW is roofline.hardware_model("TPU v5 lite")
+    assert PS.PLAN_HW.hbm_bw == 819e9
+    with pytest.raises(ValueError, match="no published peaks"):
+        roofline.hardware_model("cpu")
+    with pytest.raises(ValueError, match="no published peaks"):
+        roofline.roofline_report("", device_kind="TPU v4")

@@ -127,10 +127,9 @@ def test_decode_step_on_mesh_with_cache_sharding():
 
 def test_hierarchical_grad_reduce_three_axes():
     out = run_sub("""
-        import functools, inspect
+        import functools
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import PartitionSpec as P
-        from repro.compat import shard_map
         from repro.distributed.collectives import hierarchical_grad_reduce
         from repro.distributed.mesh import make_mesh
 
@@ -141,11 +140,9 @@ def test_hierarchical_grad_reduce_three_axes():
         # must equal a flat psum over all 8 devices, i.e. g * 8.
         # check_vma=False: the reduce-scatter/all-gather pair restores
         # replication over 'data' but the static varying-axes check cannot
-        # infer that through psum_scatter. (jax 0.4.x spells it check_rep.)
-        _ck = ("check_vma" if "check_vma" in
-               inspect.signature(shard_map).parameters else "check_rep")
-        @functools.partial(shard_map, mesh=mesh,
-                           in_specs=P(), out_specs=P(), **{_ck: False})
+        # infer that through psum_scatter.
+        @functools.partial(jax.shard_map, mesh=mesh,
+                           in_specs=P(), out_specs=P(), check_vma=False)
         def reduce_fn(g):
             return hierarchical_grad_reduce({"g": g}, intra_axis="data",
                                             inter_axis="pod")["g"]
