@@ -287,7 +287,10 @@ class LoweredKernel:
     tuned: Optional[Any] = None
 
     def run(self):
-        return self.runner()
+        if not telemetry.TRACER.enabled:
+            return self.runner()
+        with telemetry.span("run", leaf=self.leaf_name, spmd=False):
+            return self.runner()
 
     def cell_id(self) -> str:
         """Conformance-matrix cell ID: ``<expr>/<format>/<strategy>/<mesh>``
@@ -1222,16 +1225,22 @@ def _runner(jit, name, static, arrays, build):
     shapes/dtypes key component) and every Python constant baked into the
     trace must be listed in ``static``. On a key match the previously
     jitted callable is returned, so jax's compilation cache hits instead of
-    re-tracing — this is what makes a warm re-lower skip compilation."""
+    re-tracing — this is what makes a warm re-lower skip compilation.
+
+    The compiled function takes the leaf's name, so its XLA module reads
+    ``jit_<name>`` in a profile and its compile event names the leaf; the
+    call goes through :func:`~repro.runtime.telemetry.traced_runner`."""
     if not jit:
         return build()
     key = (name, tuple(static), avals_key(arrays))
+    return _RUNNER_CACHE.get_or_build(key, lambda: _jit_leaf(build(), name))
 
-    def _jit_build():
-        with telemetry.span("lower.jit", leaf=name):
-            return jax.jit(build())
 
-    return _RUNNER_CACHE.get_or_build(key, _jit_build)
+def _jit_leaf(fn, name):
+    """``jax.jit`` of ``fn`` renamed to the leaf ``name`` (jax names the
+    module after the function), behind the traced runner boundary."""
+    fn.__name__ = fn.__qualname__ = name
+    return telemetry.traced_runner(jax.jit(fn))
 
 
 def _nnz_row_windows(B: ShardedTensor, n: int):

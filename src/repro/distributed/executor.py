@@ -23,7 +23,7 @@ from jax.sharding import Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
 
 from ..core.cache import LRUCache, avals_key
-from ..core.lower import LoweredKernel
+from ..core.lower import LoweredKernel, _jit_leaf
 from ..core.tdn import Machine
 from ..kernels import ref as K
 from ..runtime import telemetry
@@ -59,14 +59,11 @@ def _spmd_runner(name, mesh, axis, static, in_specs, arrays, build):
     and the builder's arrays placed ONCE on the mesh, each under its
     ``in_specs`` entry as a NamedSharding. Every call then runs on arrays
     that already live where the program reads them: nothing lands on the
-    first device and reshards inside the program per call."""
+    first device and reshards inside the program per call. As in
+    ``core.lower._runner``, the executable is named by its leaf and called
+    through the traced runner boundary."""
     key = (name, _mesh_key(mesh), axis, tuple(static), avals_key(arrays))
-
-    def _jit_build():
-        with telemetry.span("lower.jit", leaf=name, spmd=True):
-            return jax.jit(build())
-
-    run = _SPMD_RUN_CACHE.get_or_build(key, _jit_build)
+    run = _SPMD_RUN_CACHE.get_or_build(key, lambda: _jit_leaf(build(), name))
     placed = tuple(jax.device_put(a, NamedSharding(mesh, s))
                    for a, s in zip(arrays, in_specs))
     return run, placed
@@ -917,14 +914,30 @@ def to_spmd(kernel: LoweredKernel, mesh: Mesh = None, axis: str = "x",
                 f"{sorted(OVERLAP_SPMD_BUILDERS)}")
         with telemetry.span("execute.spmd.build", leaf=kernel.leaf_name,
                             overlap=True, chunks=overlap_chunks):
-            return builder(kernel, mesh, axis=axis, chunks=overlap_chunks)
-    builder = SPMD_BUILDERS.get(kernel.leaf_name)
-    if builder is None:
-        raise NotImplementedError(
-            f"no shard_map builder for leaf {kernel.leaf_name}; "
-            "the vmap simulation backend covers it")
-    with telemetry.span("execute.spmd.build", leaf=kernel.leaf_name):
-        return builder(kernel, mesh, axis=axis)
+            call = builder(kernel, mesh, axis=axis, chunks=overlap_chunks)
+    else:
+        builder = SPMD_BUILDERS.get(kernel.leaf_name)
+        if builder is None:
+            raise NotImplementedError(
+                f"no shard_map builder for leaf {kernel.leaf_name}; "
+                "the vmap simulation backend covers it")
+        with telemetry.span("execute.spmd.build", leaf=kernel.leaf_name):
+            call = builder(kernel, mesh, axis=axis)
+    return _run_span(call, kernel.leaf_name)
+
+
+def _run_span(call, leaf: str):
+    """The shard_map ``call`` under the ``run`` span (``spmd=True``), as
+    ``LoweredKernel.run`` is on the one-chip path; ``placed`` is kept."""
+
+    def run():
+        if not telemetry.TRACER.enabled:
+            return call()
+        with telemetry.span("run", leaf=leaf, spmd=True):
+            return call()
+
+    run.placed = call.placed
+    return run
 
 
 # ---------------------------------------------------------------------------

@@ -1,14 +1,17 @@
 """Telemetry: span tracing, unified metrics, and byte-ledger verification.
 
-One observability layer for the whole lowering/execution pipeline
-(ISSUE 9). Three pieces:
+One observability layer for the whole lowering/execution pipeline. Three
+pieces:
 
 - :class:`Tracer` — a hierarchical span tracer. ``with span("lower.plan",
   sig=...)`` records a timed span nested under whatever span is open on
-  the current thread; :meth:`Tracer.export_chrome` writes Chrome
-  trace-event JSON loadable in Perfetto / ``chrome://tracing``. The
-  module-global :data:`TRACER` starts **disabled**: every instrumentation
-  site in ``core.lower`` / ``core.grid`` / ``core.partition`` /
+  the current thread. An enabled span is also a
+  ``jax.profiler.TraceAnnotation``: while a profiler session runs
+  (``jax.profiler.trace(dir, create_perfetto_trace=True)``) every span
+  lands on the trace's ``/host:CPU`` plane, on the device ops' own clock,
+  and the trace opens in Perfetto or TensorBoard. The module-global
+  :data:`TRACER` starts **disabled**: every instrumentation site in
+  ``core.lower`` / ``core.grid`` / ``core.partition`` /
   ``distributed.executor`` / ``runtime.elastic`` then costs one attribute
   read and one branch (the no-op singleton path — bounded by test).
 
@@ -27,34 +30,35 @@ One observability layer for the whole lowering/execution pipeline
   Run over the full conformance census, this pins the paper's per-axis
   communication accounting (DISTAL §5) to the implementation.
 
-Span taxonomy (all names dot-namespaced, stable — tests and CI parse
-them): ``lower`` > ``lower.plan`` / ``lower.materialize`` / ``lower.jit``
-/ ``lower.emit``; ``plan_search.search`` > ``plan_search.measure``;
-``partition.materialize``; ``execute.spmd`` / ``execute.piece``;
+Span taxonomy (all names dot-namespaced, stable — tests parse them):
+``lower`` > ``lower.plan`` / ``lower.materialize`` / ``lower.emit``;
+``run`` (one kernel call, attrs ``leaf`` / ``spmd``) > ``run.copy_in``
+(host arguments placed on the device and waited on) / ``run.execute``
+(the jitted runner through ``block_until_ready``) — the self time of
+``run`` is the copy back to the host and the output's host assembly;
+``plan_search.search`` > ``plan_search.measure``;
+``partition.materialize``; ``execute.spmd.build`` / ``execute.piece``;
 ``recovery.restore`` / ``recovery.replan`` / ``recovery.rejit``.
 
-CLI smoke (the CI trace artifact)::
-
-    PYTHONPATH=src python -m repro.runtime.telemetry --smoke \\
-        --out TRACE_smoke.json
+Instants: ``jit.compile`` (``fun_name``, ``seconds``) under whatever span
+is open when XLA compiles — a compile under ``run`` after warm-up is a
+recompile. Counters recorded while tracing: ``run.calls``,
+``run.h2d_bytes``, ``run.d2h_bytes``, ``jit.compiles``, ``jit.compile_s``.
 """
 from __future__ import annotations
 
-import argparse
-import json
 import logging
-import os
 import sys
 import threading
 import time
-from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
+from typing import Any, Callable, Dict, List, Optional, Tuple
 
+import jax
 import numpy as np
 
 __all__ = [
     "Tracer", "MetricsRegistry", "TRACER", "METRICS", "span", "instant",
-    "validate_chrome_trace", "configure_logging", "verify_byte_ledger",
-    "smoke_trace",
+    "traced_runner", "configure_logging", "verify_byte_ledger",
 ]
 
 
@@ -85,9 +89,10 @@ _NULL_SPAN = _NullSpan()
 
 class _Span:
     """One live span. Created only on the enabled path; records itself
-    into the owning tracer's event list on exit."""
+    into the owning tracer's event list on exit, and is open on the
+    profiler's trace (a ``TraceAnnotation``) for as long as it lives."""
 
-    __slots__ = ("_tracer", "name", "id", "parent", "args", "_t0")
+    __slots__ = ("_tracer", "name", "id", "parent", "args", "_t0", "_ann")
 
     def __init__(self, tracer: "Tracer", name: str, args: Dict[str, Any]):
         self._tracer = tracer
@@ -96,6 +101,7 @@ class _Span:
         self.id = 0
         self.parent: Optional[int] = None
         self._t0 = 0.0
+        self._ann = jax.profiler.TraceAnnotation(name)
 
     def set(self, **attrs) -> "_Span":
         """Attach attributes discovered after the span opened (e.g. the
@@ -111,11 +117,13 @@ class _Span:
             tr._seq += 1
             self.id = tr._seq
         stack.append(self)
+        self._ann.__enter__()
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc) -> bool:
         t1 = time.perf_counter()
+        self._ann.__exit__(None, None, None)
         tr = self._tracer
         stack = tr._stack()
         if stack and stack[-1] is self:
@@ -133,7 +141,7 @@ class _Span:
 
 
 class Tracer:
-    """Thread-safe hierarchical span tracer with Chrome trace export.
+    """Thread-safe hierarchical span tracer.
 
     Parentage is tracked per thread (a thread-local span stack) and
     recorded by span *id* at open time — a parent span finishes after its
@@ -220,50 +228,6 @@ class Tracer:
             n["children"].sort(key=lambda c: c["dur_us"] or 0, reverse=True)
         return roots
 
-    # -- export -----------------------------------------------------------
-    def export_chrome(self, path: str) -> str:
-        """Write the Chrome trace-event JSON (``{"traceEvents": [...]}``,
-        "X" complete events in µs) — open in Perfetto (ui.perfetto.dev)
-        or ``chrome://tracing``. Returns ``path``."""
-        pid = os.getpid()
-        out = []
-        for ev in self.spans():
-            args = {k: _jsonable(v) for k, v in ev["args"].items()}
-            if ev["id"] is not None:
-                args["span_id"] = ev["id"]
-                if ev["parent"] is not None:
-                    args["parent_id"] = ev["parent"]
-            rec = {"name": ev["name"], "pid": pid, "tid": ev["tid"],
-                   "ts": round(ev["ts_us"], 3), "args": args}
-            if ev["dur_us"] is None:
-                rec.update(ph="i", s="t")
-            else:
-                rec.update(ph="X", dur=round(ev["dur_us"], 3))
-            out.append(rec)
-        payload = {"traceEvents": out,
-                   "displayTimeUnit": "ms",
-                   "otherData": {"tool": "repro.runtime.telemetry"}}
-        d = os.path.dirname(path)
-        if d:
-            os.makedirs(d, exist_ok=True)
-        with open(path, "w") as fh:
-            json.dump(payload, fh, indent=1)
-        return path
-
-
-def _jsonable(v: Any) -> Any:
-    if isinstance(v, (str, int, float, bool)) or v is None:
-        return v
-    if isinstance(v, (np.integer,)):
-        return int(v)
-    if isinstance(v, (np.floating,)):
-        return float(v)
-    if isinstance(v, (tuple, list)):
-        return [_jsonable(x) for x in v]
-    if isinstance(v, dict):
-        return {str(k): _jsonable(x) for k, x in v.items()}
-    return str(v)
-
 
 #: The process-wide tracer every instrumentation site records into.
 #: Disabled by default — ``TRACER.enable()`` to start collecting.
@@ -304,35 +268,6 @@ def overlap_report(tracer: "Tracer" = None) -> Dict[str, Any]:
         "bytes": nbytes,
         "efficiency": (hidden_s / comm_s) if comm_s > 0 else 0.0,
     }
-
-
-def validate_chrome_trace(path: str,
-                          require: Sequence[str] = ()) -> Dict[str, int]:
-    """Load and structurally validate an exported trace. Asserts the
-    trace-event envelope, event field types, and that every name in
-    ``require`` appears at least once. Returns name → occurrence count."""
-    with open(path) as fh:
-        payload = json.load(fh)
-    assert isinstance(payload, dict) and "traceEvents" in payload, \
-        f"{path}: not a Chrome trace-event JSON object"
-    events = payload["traceEvents"]
-    assert isinstance(events, list) and events, f"{path}: no traceEvents"
-    counts: Dict[str, int] = {}
-    for ev in events:
-        assert isinstance(ev.get("name"), str), f"bad event name: {ev!r}"
-        assert ev.get("ph") in ("X", "i"), f"bad phase: {ev!r}"
-        assert isinstance(ev.get("ts"), (int, float)), f"bad ts: {ev!r}"
-        assert isinstance(ev.get("pid"), int) and isinstance(
-            ev.get("tid"), int), f"bad pid/tid: {ev!r}"
-        if ev["ph"] == "X":
-            assert isinstance(ev.get("dur"), (int, float)) \
-                and ev["dur"] >= 0, f"bad dur: {ev!r}"
-        counts[ev["name"]] = counts.get(ev["name"], 0) + 1
-    missing = [n for n in require if n not in counts]
-    assert not missing, (
-        f"{path}: required span names missing from trace: {missing}; "
-        f"present: {sorted(counts)}")
-    return counts
 
 
 # ---------------------------------------------------------------------------
@@ -426,6 +361,66 @@ class MetricsRegistry:
 
 #: The process-wide registry every instrumentation site records into.
 METRICS = MetricsRegistry()
+
+
+# ---------------------------------------------------------------------------
+# Compile events and the runner boundary
+# ---------------------------------------------------------------------------
+
+#: jax.monitoring event of one XLA backend compile (its ``fun_name`` is the
+#: jitted function's name: the runners carry their leaf's name).
+_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+
+
+def _on_event_duration(event: str, seconds: float, **attrs) -> None:
+    if event != _COMPILE_EVENT or not TRACER.enabled:
+        return
+    TRACER.instant("jit.compile", fun_name=attrs.get("fun_name"),
+                   seconds=seconds)
+    METRICS.counter("jit.compiles")
+    METRICS.counter("jit.compile_s", seconds)
+
+
+jax.monitoring.register_event_duration_secs_listener(_on_event_duration)
+
+
+def traced_runner(f: Callable) -> Callable:
+    """Wrap a jitted runner in the kernel-call boundary that
+    ``core.lower._runner`` and ``distributed.executor._spmd_runner`` hand
+    out. Disabled, a call is ``f(*args)`` behind one branch. Enabled:
+
+    - ``run.copy_in``: the host-resident (numpy) argument leaves are placed
+      on the device and waited on; counter ``run.h2d_bytes``;
+    - ``run.execute``: ``f`` through ``block_until_ready``; counters
+      ``run.d2h_bytes`` (every caller copies all outputs to the host) and
+      ``run.calls``.
+
+    A kernel cannot start before its inputs land, and the caller's
+    ``np.asarray`` waits for the result anyway, so tracing changes the
+    device timeline by one host wait per call."""
+
+    def call(*args):
+        if not TRACER.enabled:
+            return f(*args)
+        leaves, tree = jax.tree_util.tree_flatten(args)
+        host = [i for i, x in enumerate(leaves) if isinstance(x, np.ndarray)]
+        with TRACER.span("run.copy_in", arrays=len(host)) as sp:
+            placed = jax.block_until_ready(
+                jax.device_put([leaves[i] for i in host]))
+            h2d = sum(int(x.nbytes) for x in placed)
+            sp.set(bytes=h2d)
+        for i, x in zip(host, placed):
+            leaves[i] = x
+        with TRACER.span("run.execute"):
+            out = jax.block_until_ready(
+                f(*jax.tree_util.tree_unflatten(tree, leaves)))
+        METRICS.counter("run.calls")
+        METRICS.counter("run.h2d_bytes", h2d)
+        METRICS.counter("run.d2h_bytes", sum(
+            int(x.nbytes) for x in jax.tree_util.tree_leaves(out)))
+        return out
+
+    return call
 
 
 def configure_logging(level: int = logging.INFO) -> logging.Logger:
@@ -550,87 +545,3 @@ def verify_byte_ledger(kernel) -> Dict[str, Any]:
             + f" predicted={c['predicted']} ledger={c['ledger']}"
             for c in bad))
     return report
-
-
-# ---------------------------------------------------------------------------
-# Smoke trace (CI artifact) — a traced 2-D grid SpMM lower + execute
-# ---------------------------------------------------------------------------
-
-
-def smoke_trace(out_path: str, n: int = 512, m: int = 512, j: int = 16,
-                ) -> Dict[str, int]:
-    """Lower + execute one SpMM on a 2x2 machine grid with tracing on,
-    profile per-piece leaf wall times, verify the byte ledger, export the
-    Chrome trace, and validate it. Returns the span-name counts. This is
-    the CI `TRACE_smoke.json` producer and the acceptance-criteria check
-    in one function."""
-    import repro.core as rc
-    from repro.core import formats as F
-    from repro.core.lower import (clear_lowering_caches,
-                                  default_grid_schedule, lower)
-    from repro.core.tensor import Tensor
-    from repro.distributed.executor import profile_pieces
-
-    rng = np.random.default_rng(0)
-    dB = ((rng.random((n, m)) < 0.05)
-          * rng.standard_normal((n, m))).astype(np.float32)
-    B = Tensor.from_dense("B", dB, F.CSR())
-    C = Tensor.from_dense("C", rng.standard_normal((m, j)).astype(np.float32))
-    stmt = rc.parse_tin("A(i,j) = B(i,k) * C(k,j)",
-                        A=Tensor.zeros_dense("A", (n, j)), B=B, C=C)
-    machine = rc.Machine(("x", 2), ("y", 2))
-
-    clear_lowering_caches()
-    TRACER.clear()
-    TRACER.enable()
-    try:
-        kernel = lower(stmt, machine,
-                       schedule=default_grid_schedule(stmt, machine))
-        with TRACER.span("execute", leaf=kernel.leaf_name):
-            kernel.run()
-        prof = profile_pieces(kernel, iters=2, warmup=1)
-        verify_byte_ledger(kernel)
-    finally:
-        TRACER.disable()
-    TRACER.export_chrome(out_path)
-    counts = validate_chrome_trace(out_path, require=(
-        "lower", "lower.plan", "lower.materialize", "lower.jit",
-        "execute", "execute.piece"))
-    assert counts["execute.piece"] >= kernel.strategy.pieces, (
-        f"expected per-piece timings for all {kernel.strategy.pieces} "
-        f"pieces, saw {counts['execute.piece']} execute.piece spans")
-    assert prof.seconds.shape[0] == kernel.strategy.pieces
-    return counts
-
-
-def _main(argv: Optional[List[str]] = None) -> int:
-    ap = argparse.ArgumentParser(
-        prog="python -m repro.runtime.telemetry",
-        description="telemetry utilities (smoke trace / trace validation)")
-    ap.add_argument("--smoke", action="store_true",
-                    help="run a traced 2-D grid SpMM lower+execute")
-    ap.add_argument("--out", default="TRACE_smoke.json",
-                    help="trace output path (with --smoke)")
-    ap.add_argument("--validate", metavar="TRACE",
-                    help="validate an existing Chrome trace JSON")
-    args = ap.parse_args(argv)
-    if args.validate:
-        counts = validate_chrome_trace(args.validate)
-        print(json.dumps(counts, indent=2, sort_keys=True))
-        return 0
-    if args.smoke:
-        counts = smoke_trace(args.out)
-        print(f"wrote {args.out}")
-        print(json.dumps(counts, indent=2, sort_keys=True))
-        return 0
-    ap.error("nothing to do: pass --smoke or --validate")
-    return 2
-
-
-if __name__ == "__main__":
-    # `python -m repro.runtime.telemetry` executes this file as __main__,
-    # a SECOND module instance whose TRACER is not the one the pipeline's
-    # `from ..runtime import telemetry` records into — delegate to the
-    # canonical instance so --smoke traces the real global tracer.
-    import repro.runtime.telemetry as _canonical
-    raise SystemExit(_canonical._main())

@@ -1,11 +1,19 @@
-"""Telemetry subsystem (ISSUE 9): hierarchical span tracer + Chrome
-trace export, metrics registry snapshot, byte-ledger verification,
-per-piece kernel profiling -> weighted re-plan, explain() provenance,
-and the span-derived RecoveryReport time-split invariant (the
+"""Telemetry subsystem: hierarchical span tracer on the profiler's
+clock, the kernel-call boundary (copy-in / execute spans, transfer byte
+counters, compile events), metrics registry snapshot, byte-ledger
+verification, per-piece kernel profiling -> weighted re-plan, explain()
+provenance, and the span-derived RecoveryReport time-split invariant (the
 double-count bugfix regression)."""
+import glob
 import logging
+import os
+import subprocess
+import sys
+import textwrap
 import threading
 import time
+
+import jax
 
 import numpy as np
 import pytest
@@ -13,9 +21,10 @@ import pytest
 import repro.core as rc
 from repro.core import formats as F
 from repro.core.interp import interpret
-from repro.core.lower import (clear_lowering_caches, default_grid_schedule,
-                              default_nnz_schedule, default_row_schedule,
-                              lower, relower)
+from repro.core.lower import (LoweredKernel, clear_lowering_caches,
+                              default_grid_schedule, default_nnz_schedule,
+                              default_row_schedule, lower, rebind_dense,
+                              relower)
 from repro.core.tensor import Tensor
 from repro.distributed.executor import profile_pieces
 from repro.launch.report import telemetry_table
@@ -55,10 +64,10 @@ def _spmm(n=48, m=40, j=8, seed=2, fm=None):
 
 
 # ---------------------------------------------------------------------------
-# Tracer core: nesting, threads, Chrome export round-trip
+# Tracer core: nesting, threads, the profiler's trace
 # ---------------------------------------------------------------------------
 
-def test_span_nesting_and_chrome_roundtrip(tmp_path):
+def test_span_nesting_call_tree():
     tr = telemetry.Tracer(enabled=True)
     with tr.span("outer", who="test"):
         with tr.span("inner.a", k=1):
@@ -76,12 +85,11 @@ def test_span_nesting_and_chrome_roundtrip(tmp_path):
     t.start()
     t.join()
 
-    path = str(tmp_path / "trace.json")
-    assert tr.export_chrome(path) == path
-    counts = telemetry.validate_chrome_trace(
-        path, require=("outer", "inner.a", "inner.b", "leaf",
-                       "tick", "thread.root"))
-    assert counts["outer"] == 1 and counts["tick"] == 1
+    counts = {}
+    for ev in tr.spans():
+        counts[ev["name"]] = counts.get(ev["name"], 0) + 1
+    assert counts == {"outer": 1, "inner.a": 1, "inner.b": 1, "leaf": 1,
+                      "tick": 1, "thread.root": 1}
 
     # call_tree reconstructs the nesting from recorded parent ids
     roots = tr.call_tree()
@@ -95,6 +103,38 @@ def test_span_nesting_and_chrome_roundtrip(tmp_path):
     # parent spans strictly contain their children in time
     assert outer["dur_us"] >= inner_b["dur_us"] >= inner_b["children"][0][
         "dur_us"]
+
+
+def _host_events(trace_dir):
+    """(name, start_ns, dur_ns) of every event on the trace's /host:CPU."""
+    from jax.profiler import ProfileData
+    paths = glob.glob(os.path.join(trace_dir, "**", "*.xplane.pb"),
+                      recursive=True)
+    assert len(paths) == 1, paths
+    plane = next(p for p in ProfileData.from_file(paths[0]).planes
+                 if p.name == "/host:CPU")
+    return [(ev.name, ev.start_ns, ev.duration_ns)
+            for line in plane.lines for ev in line.events]
+
+
+def test_enabled_span_lands_on_the_profiler_trace(tmp_path):
+    tr = telemetry.Tracer(enabled=True)
+    f = jax.jit(lambda x: (x * 2.0).sum())
+    x = np.arange(1024, dtype=np.float32)
+    f(x).block_until_ready()
+    with jax.profiler.trace(str(tmp_path)):
+        with tr.span("probe.outer"):
+            with tr.span("probe.inner"):
+                f(x).block_until_ready()
+        with telemetry.Tracer(enabled=False).span("probe.disabled"):
+            pass
+    evs = {name: (t0, dur) for name, t0, dur in _host_events(str(tmp_path))}
+    assert "probe.outer" in evs and "probe.inner" in evs
+    assert "probe.disabled" not in evs
+    (o0, od), (i0, idur) = evs["probe.outer"], evs["probe.inner"]
+    assert o0 <= i0 and i0 + idur <= o0 + od
+    # the in-memory record is unchanged by the annotation
+    assert [e["name"] for e in tr.spans()] == ["probe.inner", "probe.outer"]
 
 
 def test_disabled_tracer_is_noop_and_cheap():
@@ -151,26 +191,195 @@ def test_disabled_tracer_no_measurable_warm_relower_overhead():
     assert n_events * unit < max(warm_s, 1e-4) * 0.05
 
 
+def test_disabled_tracer_no_measurable_run_overhead():
+    """With the global tracer disabled, run() passes two branches (the
+    ``run`` span site and the runner boundary) on its way to the jitted
+    runner. Their cost, measured on a no-op runner, is a small fraction of
+    the smallest real run()."""
+    stmt = _spmv()
+    k = lower(stmt, M4, schedule=default_nnz_schedule(stmt, M4))
+    assert not telemetry.TRACER.enabled
+    k.run()                                           # compile
+    reps = 200
+    t0 = time.perf_counter()
+    for _ in range(reps):
+        k.run()
+    run_s = (time.perf_counter() - t0) / reps
+
+    noop = telemetry.traced_runner(lambda *a: None)
+    kern = LoweredKernel(
+        stmt=None, strategy=None, machine=None, plans={}, shards={},
+        runner=lambda: noop(1, 2, 3), comm=None, leaf_name="noop")
+    reps = 20000
+    t0 = time.perf_counter()
+    for _ in range(reps):
+        kern.run()
+    unit = (time.perf_counter() - t0) / reps
+    assert unit < 20e-6          # generous bound; typically well under 1us
+    assert unit < run_s * 0.05
+
+
 # ---------------------------------------------------------------------------
-# Pipeline instrumentation: traced grid lower+execute (the CI smoke body)
+# The kernel-call boundary: run / run.copy_in / run.execute, byte counters,
+# compile events, runners named by leaf
 # ---------------------------------------------------------------------------
 
-def test_smoke_trace_grid_spmm(tmp_path):
-    path = str(tmp_path / "TRACE_smoke.json")
-    counts = telemetry.smoke_trace(path, n=128, m=128, j=8)
-    # smoke_trace already validates; pin the span taxonomy here too
-    for name in ("lower", "lower.plan", "lower.materialize", "lower.jit",
-                 "lower.emit", "execute", "execute.piece"):
-        assert counts.get(name, 0) >= 1, f"missing span {name}"
-    assert counts["execute.piece"] >= 4          # 2x2 grid -> >=4 pieces
-    # the global tracer still holds the events (disabled, not cleared):
-    # lowering spans must be nested under the top-level "lower" span
-    roots = telemetry.TRACER.call_tree()
-    lower_roots = [r for r in roots if r["name"] == "lower"]
-    assert lower_roots
-    kids = {c["name"] for r in lower_roots for c in r["children"]}
-    assert {"lower.plan", "lower.materialize", "lower.emit"} <= kids
+@pytest.fixture
+def runner_calls(monkeypatch):
+    """Every (jitted runner, host arguments) pair called through the
+    traced runner boundary, from a cold runner cache."""
+    seen = []
+    orig = telemetry.traced_runner
+
+    def spy(f):
+        def g(*args):
+            seen.append((f, args))
+            return f(*args)
+        return orig(g)
+
+    monkeypatch.setattr(telemetry, "traced_runner", spy)
+    clear_lowering_caches()
+    telemetry.METRICS.clear()
     telemetry.TRACER.clear()
+    telemetry.TRACER.enable()
+    yield seen
+    telemetry.TRACER.disable()
+    telemetry.TRACER.clear()
+    telemetry.METRICS.clear()
+    clear_lowering_caches()
+
+
+_BOUNDARY_CASES = [(_spmv, default_row_schedule, "spmv_rows"),
+                   (_spmv, default_nnz_schedule, "spmv_nnz"),
+                   (_spmm, default_row_schedule, "spmm_rows"),
+                   (_spmm, default_nnz_schedule, "spmm_nnz")]
+
+
+@pytest.mark.parametrize("mk, sched, leaf", _BOUNDARY_CASES)
+def test_run_counts_argument_and_output_bytes(runner_calls, mk, sched, leaf):
+    """h2d is every host argument byte the compiled runner reads and d2h
+    every output byte it returns, by XLA's own memory analysis."""
+    stmt = mk()
+    k = lower(stmt, M4, schedule=sched(stmt, M4))
+    assert k.leaf_name == leaf
+    y = k.run()
+    np.testing.assert_allclose(y, interpret(stmt), rtol=1e-5, atol=1e-5)
+    (f, args), = runner_calls
+    mem = f.lower(*args).compile().memory_analysis()
+    c = telemetry.METRICS.snapshot()["counters"]
+    assert c["run.calls"] == 1
+    assert c["run.h2d_bytes"] == mem.argument_size_in_bytes > 0
+    assert c["run.d2h_bytes"] == mem.output_size_in_bytes == y.nbytes
+
+    roots = [r for r in telemetry.TRACER.call_tree() if r["name"] == "run"]
+    assert len(roots) == 1 and roots[0]["args"] == {"leaf": leaf,
+                                                    "spmd": False}
+    kids = {c["name"]: c for c in roots[0]["children"]}
+    assert set(kids) == {"run.copy_in", "run.execute"}
+    assert kids["run.copy_in"]["args"]["bytes"] == c["run.h2d_bytes"]
+
+
+@pytest.mark.parametrize("mk, sched, leaf", _BOUNDARY_CASES)
+def test_runner_module_is_named_by_leaf(runner_calls, mk, sched, leaf):
+    stmt = mk()
+    lower(stmt, M4, schedule=sched(stmt, M4)).run()
+    (f, args), = runner_calls
+    assert f.lower(*args).compile().as_text().startswith(
+        f"HloModule jit_{leaf}")
+    compiles = [e for e in telemetry.TRACER.spans()
+                if e["name"] == "jit.compile"]
+    assert [e["args"]["fun_name"] for e in compiles] == [f"jit({leaf})"]
+
+
+def test_rebind_and_run_again_does_not_recompile(runner_calls):
+    """The first traced run() compiles once, under run.execute; later
+    rebinds with same-shape operands, traced or not, never compile."""
+    stmt = _spmm()
+    k = lower(stmt, M4, schedule=default_nnz_schedule(stmt, M4))
+    k.run()
+    events = telemetry.TRACER.spans()
+    compiles = [e for e in events if e["name"] == "jit.compile"]
+    assert len(compiles) == 1 and compiles[0]["args"]["seconds"] > 0
+    execute = next(e for e in events if e["name"] == "run.execute")
+    assert compiles[0]["parent"] == execute["id"]
+    c = telemetry.METRICS.snapshot()["counters"]
+    assert c["jit.compiles"] == 1
+    assert c["jit.compile_s"] == pytest.approx(compiles[0]["args"]["seconds"])
+
+    rng = np.random.default_rng(9)
+    for traced in (False, True):
+        C = Tensor.from_dense("C", rng.standard_normal((40, 8)).astype(
+            np.float32))
+        k = rebind_dense(k, {"C": C})
+        telemetry.TRACER.enabled = traced
+        y = k.run()
+        np.testing.assert_allclose(y, interpret(k.stmt), rtol=1e-5,
+                                   atol=1e-5)
+    assert len(runner_calls) == 3
+    assert telemetry.METRICS.snapshot()["counters"]["jit.compiles"] == 1
+    assert sum(e["name"] == "jit.compile"
+               for e in telemetry.TRACER.spans()) == 1
+    assert sum(e["name"] == "run" for e in telemetry.TRACER.spans()) == 2
+
+
+def test_to_spmd_counts_no_copy_in_on_four_devices():
+    """Through to_spmd the shards are placed once, so a traced call copies
+    nothing in; what it copies out is the replicated psum'd output."""
+    src = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "src")
+    prog = textwrap.dedent("""
+        import numpy as np
+        import repro.core as rc
+        from repro.core import formats as F
+        from repro.core.interp import interpret
+        from repro.core.lower import default_nnz_schedule, lower
+        from repro.core.tensor import Tensor
+        from repro.distributed.executor import to_spmd
+        from repro.runtime import telemetry
+
+        rng = np.random.default_rng(3)
+        d = (rng.random((60, 50)) < 0.2) * rng.standard_normal((60, 50))
+        B = Tensor.from_dense("B", d.astype(np.float32), F.CSR())
+        for j in (None, 8):
+            if j is None:
+                c = Tensor.from_dense("c", rng.standard_normal(50).astype(
+                    np.float32))
+                stmt = rc.parse_tin("a(i) = B(i,j) * c(j)", B=B, c=c,
+                                    a=Tensor.zeros_dense("a", (60,)))
+            else:
+                C = Tensor.from_dense("C", rng.standard_normal(
+                    (50, j)).astype(np.float32))
+                stmt = rc.parse_tin("A(i,j) = B(i,k) * C(k,j)", B=B, C=C,
+                                    A=Tensor.zeros_dense("A", (60, j)))
+            M = rc.Machine(("x", 4))
+            k = lower(stmt, M, schedule=default_nnz_schedule(stmt, M))
+            call = to_spmd(k)
+            assert len(call.placed[0].sharding.mesh.devices.flat) == 4
+            telemetry.METRICS.clear()
+            telemetry.TRACER.clear()
+            telemetry.TRACER.enable()
+            y = call()
+            telemetry.TRACER.disable()
+            np.testing.assert_allclose(y, interpret(stmt), rtol=1e-5,
+                                       atol=1e-5)
+            c = telemetry.METRICS.snapshot()["counters"]
+            assert c["run.calls"] == 1, c
+            assert c["run.h2d_bytes"] == 0, c
+            assert c["run.d2h_bytes"] == y.nbytes, (c, y.nbytes)
+            run, = [r for r in telemetry.TRACER.call_tree()
+                    if r["name"] == "run"]
+            assert run["args"] == {"leaf": k.leaf_name, "spmd": True}
+            assert {ch["name"] for ch in run["children"]} == {
+                "run.copy_in", "run.execute"}
+        print("OK")
+    """)
+    env = dict(os.environ, PYTHONPATH=src,
+               JAX_PLATFORMS=os.environ.get("JAX_PLATFORMS", "cpu"),
+               XLA_FLAGS="--xla_force_host_platform_device_count=4")
+    res = subprocess.run([sys.executable, "-c", prog], capture_output=True,
+                         text=True, timeout=300, env=env)
+    assert res.returncode == 0, res.stderr[-3000:]
+    assert res.stdout.strip().endswith("OK")
 
 
 # ---------------------------------------------------------------------------
