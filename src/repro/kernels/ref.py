@@ -63,15 +63,49 @@ def dense_spmttkrp(B, C, D):
 # Shard-leaf helpers
 # ---------------------------------------------------------------------------
 
+def _prefix_sum(x: jnp.ndarray) -> jnp.ndarray:
+    """Inclusive prefix sum of a 1-D integer array: ceil(log2 n) passes of
+    ``x += x shifted right by 2**i`` (Hillis–Steele) in one loop.
+
+    Used by ``rows_from_pos`` in place of ``jnp.cumsum`` to keep the row
+    leaves' peak device memory where it was: that peak counts the
+    executable's code, and on a TPU the cumsum's reduce-window lowering
+    adds ≈ 2.8 MB of it to an arxiv-sized row SpMM (+1.3%), the loop
+    ≈ 0.5 MB. The segment leaves below keep ``jnp.cumsum``."""
+    n = x.shape[0]
+    if n <= 1:
+        return x
+    zeros = jnp.zeros_like(x)
+
+    def step(i, x):
+        shift = jnp.left_shift(1, i)
+        return x + jax.lax.dynamic_slice(jnp.concatenate([zeros, x]),
+                                         (n - shift,), (n,))
+
+    return jax.lax.fori_loop(0, (n - 1).bit_length(), step, x)
+
+
 def rows_from_pos(pos: jnp.ndarray, n_positions: int) -> jnp.ndarray:
     """Expand a local pos array to a per-position parent index.
 
-    ``pos``: (R+1,) monotone int32. Returns (n_positions,) row ids; padded
-    positions (>= pos[-1]) clip to the last row, harmless since their vals
-    are zero."""
-    p = jnp.arange(n_positions, dtype=pos.dtype)
-    r = jnp.searchsorted(pos, p, side="right") - 1
-    return jnp.clip(r, 0, pos.shape[0] - 2)
+    ``pos``: (R+1,) monotone int32. Returns (n_positions,) row ids:
+    ``rows[p] = clip(searchsorted(pos, p, "right") - 1, 0, R-1)``. Padded
+    positions (>= pos[-1]) land on the last row, harmless since their vals
+    are zero.
+
+    The rows are counted, not searched: ``rows[p] = #{r in 1..R-1 :
+    pos[r] <= p}``, one scatter-add of R-1 ones at ``pos[1:-1]`` (an empty
+    row adds twice at one position; starts past the last position drop) and
+    a prefix sum over the positions. That is O(R + N log N) work with no
+    dependent gathers, where a binary search does ``ceil(log2(R+2))``
+    gathers per position.
+
+    The rows are not computed once on the host, though ``pos`` is fixed
+    across rebinds: they would add 4 bytes per stored position to every
+    call's arguments and copy-in."""
+    flags = jnp.zeros((n_positions,), pos.dtype).at[pos[1:-1]].add(
+        1, mode="drop")
+    return _prefix_sum(flags)
 
 
 # ---------------------------------------------------------------------------
