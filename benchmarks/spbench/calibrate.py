@@ -36,7 +36,7 @@ def main(argv=None) -> int:
     for seed in (int(s) for s in args.seeds.split(",")):
         t0 = time.perf_counter()
         B = gen.generate(cfg, seed, cfg["sparse"])
-        pool = stmt_mod.operands(cfg, B.shape[0], args.pool, seed)
+        pool = stmt_mod.operands(cfg, B, args.pool, seed)
         ref = stmt_mod.Reference(B)
         errs = [ref.control_error(d) for d in pool]
         readings.append(min(errs))

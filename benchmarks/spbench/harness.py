@@ -6,7 +6,15 @@ Every piece is found by name (:mod:`spec`): the operand's generator
 (``data/``), the statement's operands, work and plain reference
 (``reference/``), the loop the window drives (``loops/``) and the
 per-layer readers (``layer_metrics/``). Nothing here names a cell, a
-statement or a loop.
+statement or a loop: the loop lowers what its step needs
+(``make(cfg, B, pool, mix, parts) -> (step, devices, kernels)``), and
+the harness logs every kernel and refuses a run in which one fell back.
+
+A reader gets ``ctx``: the program's spans over set-up (``spans``), the
+deltas of its counters over set-up and over the window
+(``setup_counters``, ``counters``), the window's iterations, the loop's
+rebind seconds, the reduced trace (:func:`devtrace.reduce`), the
+statement's work, the chip's peaks and the number of chips.
 
 An iteration ends when ``jax.block_until_ready`` returns on its result.
 After the window, the outputs of a sample of iterations drawn from the
@@ -46,18 +54,6 @@ class Parts:
         self.parts[name] = self.parts.get(name, 0.0) + \
             time.perf_counter() - t0
         return out
-
-
-def lower(stmt, mix: dict):
-    """Lower under the traffic's machine and its named default schedule
-    (``default_<schedule>_schedule`` of the program)."""
-    import repro.core as rc
-    machine = rc.Machine(*[tuple(ax) for ax in mix["machine"]])
-    sched = getattr(rc, f"default_{mix['schedule']}_schedule", None)
-    if sched is None:
-        raise LookupError(f"the program has no schedule "
-                          f"{mix['schedule']!r}")
-    return rc.lower_stmt(stmt, machine, schedule=sched(stmt, machine))
 
 
 class Sample:
@@ -120,43 +116,82 @@ def _metric(value, unit):
     return {"value": value, "unit": unit}
 
 
+def _counters() -> dict:
+    from repro.runtime import telemetry
+    return telemetry.METRICS.snapshot()["counters"]
+
+
+def _delta(before: dict, after: dict) -> dict:
+    return {k: v - before.get(k, 0.0) for k, v in after.items()
+            if v != before.get(k, 0.0)}
+
+
+def _check_kernels(kernels) -> None:
+    """Log every kernel the loop lowered; refuse the run if any of them
+    fell back to a format conversion or a generic leaf."""
+    for k in kernels:
+        log("lowered " + json.dumps({
+            "cell": k.cell_id(), "leaf": k.leaf_name,
+            "fallbacks": k.fallbacks}))
+        log("comm (planned bytes) " + json.dumps(k.comm.as_dict()))
+    bad = [(k.leaf_name, k.fallbacks) for k in kernels
+           if k.fallbacks or k.leaf_name.startswith("generic")]
+    if bad or not kernels:
+        raise RuntimeError(f"cell did not lower directly: {bad}")
+
+
+class _Compiles:
+    """XLA compiles in the process, counted whether or not the program's
+    tracer is on, so the log shows that none falls in the window."""
+    count = 0
+    _listening = False
+
+    @classmethod
+    def listen(cls) -> None:
+        import jax
+        if not cls._listening:
+            jax.monitoring.register_event_duration_secs_listener(
+                cls._on_event)
+            cls._listening = True
+
+    @classmethod
+    def _on_event(cls, event, seconds, **attrs):
+        if event == "/jax/core/compile/backend_compile_duration":
+            cls.count += 1
+
+
 def run_cell(cell: dict, seed: int, seconds: float, trace: bool,
              t_start: float, peaks: dict | None) -> dict:
-    """Run one cell; returns the result object (the last stdout line)."""
+    """Run one cell; returns the result object (the last stdout line).
+
+    The program's tracer records through set-up and warm-up, and through
+    the window only when ``trace`` is set: an untraced window runs the
+    program as a user does."""
+    from repro.runtime import telemetry
+    _Compiles.listen()
+    telemetry.TRACER.clear()
+    telemetry.TRACER.enable()
+    try:
+        return _run(cell, seed, seconds, trace, t_start, peaks)
+    finally:
+        telemetry.TRACER.disable()
+
+
+def _run(cell, seed, seconds, trace, t_start, peaks):
     import jax
     from repro.runtime import telemetry
 
     cfg, mix = cell["config"], cell["traffic"]
     stmt_mod = cell["reference"]
     parts = Parts()
+    counters0 = _counters()
     B = parts.time("generate_s", cell["generator"].generate, cfg, seed,
                    cfg["sparse"])
-    pool = parts.time("operands_s", stmt_mod.operands, cfg, B.shape[0],
+    pool = parts.time("operands_s", stmt_mod.operands, cfg, B,
                       int(mix["operand_pool"]), seed)
-    stmt = stmt_mod.statement(cfg, B, pool[0])
-    telemetry.TRACER.clear()
-    telemetry.TRACER.enable()
-    try:
-        kernel = parts.time("lower_s", lower, stmt, mix)
-    finally:
-        telemetry.TRACER.disable()
-    spans = telemetry.TRACER.spans()
-    log("lowered " + json.dumps({
-        "cell": kernel.cell_id(), "leaf": kernel.leaf_name,
-        "fallbacks": kernel.fallbacks, "nnz": int(B.nnz),
-        "shape": list(B.shape), "dense_cols": int(cfg["dense_cols"])}))
-    lower_spans = {}
-    for s in spans:
-        if s["dur_us"] is not None and s["name"].startswith("lower"):
-            lower_spans[s["name"]] = lower_spans.get(s["name"], 0.0) + \
-                s["dur_us"] * 1e-6
-    log("lower spans (s) " + json.dumps(lower_spans))
-    log("comm (planned bytes) " + json.dumps(kernel.comm.as_dict()))
-    if kernel.fallbacks or kernel.leaf_name.startswith("generic"):
-        raise RuntimeError(f"cell did not lower directly: "
-                           f"{kernel.leaf_name} {kernel.fallbacks}")
-
-    step, devices = cell["loop"].make(kernel, cfg, pool, parts)
+    log("operands " + json.dumps(stmt_mod.sizes(cfg, B)))
+    step, devices, kernels = cell["loop"].make(cfg, B, pool, mix, parts)
+    _check_kernels(kernels)
     devices = devices or [jax.devices()[0]]
     if len(devices) != int(mix["chips"]):
         raise RuntimeError(f"the loop runs on {len(devices)} devices, the "
@@ -167,17 +202,32 @@ def run_cell(cell: dict, seed: int, seconds: float, trace: bool,
     del y
     gc.collect()
     setup_s = time.perf_counter() - t_start
+    spans = telemetry.TRACER.spans()
+    counters1 = _counters()
+    setup_counters = _delta(counters0, counters1)
+    if not trace:
+        telemetry.TRACER.disable()
+    lower_spans = {}
+    for s in spans:
+        if s["dur_us"] is not None and s["name"].startswith("lower"):
+            lower_spans[s["name"]] = lower_spans.get(s["name"], 0.0) + \
+                s["dur_us"] * 1e-6
+    log("lower spans (s) " + json.dumps(lower_spans))
+    log("setup counters " + json.dumps(setup_counters))
     log("setup " + json.dumps({"setup_s": setup_s, **parts.parts}))
 
     sample = Sample(int(mix["check_sample"]), seed)
     trace_dir = tempfile.mkdtemp(prefix="spbench_trace_") if trace else None
     if trace:
         jax.profiler.start_trace(trace_dir)
+    compiles0 = _Compiles.count
     try:
         iters, rebinds, window_s = _window(step, seconds, sample)
     finally:
         if trace:
             jax.profiler.stop_trace()
+    telemetry.TRACER.disable()
+    counters = _delta(counters1, _counters())
     peak = _peak_bytes(devices)
     n = len(iters)
     iter_ms = window_s / n * 1e3
@@ -186,7 +236,10 @@ def run_cell(cell: dict, seed: int, seconds: float, trace: bool,
         "iterations": n, "window_s": window_s, "iter_ms": iter_ms,
         "iter_p95_ms": p95_ms,
         "iter_median_ms": statistics.median(iters) * 1e3,
-        "iter_max_ms": max(iters) * 1e3, "memory_peak_bytes": peak}))
+        "iter_max_ms": max(iters) * 1e3, "memory_peak_bytes": peak,
+        "compiles": _Compiles.count - compiles0}))
+    if trace:
+        log("window counters " + json.dumps(counters))
 
     reduced = None
     if trace:
@@ -199,12 +252,13 @@ def run_cell(cell: dict, seed: int, seconds: float, trace: bool,
             reduced = devtrace.reduce(loaded, planes)
             log("trace " + json.dumps({
                 k: reduced[k] for k in ("window_s", "per_device",
-                                        "busiest")}))
+                                        "busiest", "modules",
+                                        "idle_by_span")}))
         elif peaks is not None:
             raise RuntimeError(f"the trace holds no ops of {planes}; "
                                f"planes: {sorted(loaded)}")
 
-    del step, kernel
+    del step, kernels
     gc.collect()
     t_check = time.perf_counter()
     ref = stmt_mod.Reference(B)
@@ -226,7 +280,8 @@ def run_cell(cell: dict, seed: int, seconds: float, trace: bool,
     units = {m["name"]: m["unit"] for m in
              cell["end_to_end"] + cell["per_layer"]}
     if trace:
-        ctx = {"spans": spans, "iterations": n, "rebind_s": rebinds,
+        ctx = {"spans": spans, "setup_counters": setup_counters,
+               "counters": counters, "iterations": n, "rebind_s": rebinds,
                "trace": reduced, "work": work, "peaks": peaks,
                "chips": len(devices)}
         metrics = {}

@@ -4,13 +4,16 @@ layer brings a new right-hand side every step. A memoised result cannot
 pass for a fast one."""
 import time
 
+import lowering
 
-def make(kernel, cfg, pool, parts):
-    """(step, devices): step(i) -> (output, pool index, rebind seconds);
-    ``devices`` None means the default device."""
+
+def make(cfg, B, pool, mix, parts):
+    """(step, devices, kernels): step(i) -> (output, pool index, rebind
+    seconds); ``devices`` None means the default device."""
     from jax.profiler import TraceAnnotation
     from repro.core import lower as lower_mod
     from repro.core.tensor import Tensor
+    kernel = lowering.lower_statement(cfg, B, pool[0], mix, parts)
     name = cfg["dense"]
     tensors = [Tensor.from_dense(name, d) for d in pool]
 
@@ -24,4 +27,4 @@ def make(kernel, cfg, pool, parts):
             y = k.run()
         return y, p, t1 - t0
 
-    return step, None
+    return step, None, [kernel]

@@ -37,10 +37,11 @@ INDEX_BYTES = 4
 VALUE_BYTES = 4
 
 
-def operands(cfg: dict, n: int, size: int, seed: int) -> list:
-    """``size`` dense operands C of ``n`` rows, standard normal float32."""
+def operands(cfg: dict, B, size: int, seed: int) -> list:
+    """``size`` dense operands C, one row per column of ``B``, standard
+    normal float32."""
     rng = np.random.default_rng([DENSE_STREAM, seed])
-    j = int(cfg["dense_cols"])
+    n, j = B.shape[1], int(cfg["dense_cols"])
     shape = (n,) if j == 1 else (n, j)
     return [rng.standard_normal(shape, dtype=np.float32)
             for _ in range(size)]
@@ -55,6 +56,12 @@ def statement(cfg: dict, B, dense):
         cfg["output"]: Tensor.zeros_dense(cfg["output"], out_shape),
         cfg["sparse"]: B,
         cfg["dense"]: Tensor.from_dense(cfg["dense"], dense)})
+
+
+def sizes(cfg: dict, B) -> dict:
+    """The sizes a run logs beside its kernels."""
+    return {"nnz": int(B.nnz), "shape": list(B.shape),
+            "dense_cols": int(cfg["dense_cols"])}
 
 
 def count(n_rows: int, n_cols: int, nnz: int, j: int) -> dict:

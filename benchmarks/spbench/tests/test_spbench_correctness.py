@@ -51,7 +51,7 @@ def test_program_passes_and_bf16_control_fails_the_limit(config):
     limit = float(cfg["err_limit"])
     for seed in (1, 2, 3):
         B = spec.module("data", cfg["generator"]).generate(cfg, seed)
-        dense = stmt_mod.operands(cfg, B.shape[0], 1, seed)[0]
+        dense = stmt_mod.operands(cfg, B, 1, seed)[0]
         ref = stmt_mod.Reference(B)
         # float32 accumulation of the exact float32 products
         got = (stmt_mod.scipy_csr(B, np.float32) @ dense).astype(np.float32)

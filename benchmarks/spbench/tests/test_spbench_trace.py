@@ -15,16 +15,32 @@ import spec  # noqa: E402
 D0, D1 = "/device:TPU:0", "/device:TPU:1"
 HOST = devtrace.HOST_PLANE
 # Window 0..1000 ns. Device 0: ops at 100-300 and 200-400 (overlap) and
-# 900-1100 (clipped to 1000); an all-reduce at 500-600. Device 1: 0-100.
+# 900-1100 (clipped to 1000); an all-reduce at 500-600; two modules. Its
+# idle gaps 0-100, 400-500 and 600-900 fall under these host spans:
+#   0-10 none, 10-90 bench.rebind, 90-100 bench.run,
+#   400-450 run.copy_in, 450-500 run.execute,
+#   600-620 run.execute, 620-700 run (copy-out), 700-790 bench.run,
+#   790-800 bench.result, 800-850 bench.run, 850-880 run.copy_in,
+#   880-900 run.execute.
+# Device 1: 0-100.
 SYNTH = {
     D0: {devtrace.OP_LINE: [["fusion.1", 100, 200], ["fusion.2", 200, 200],
                             ["all-reduce.3", 500, 100],
-                            ["fusion.1", 900, 200]]},
+                            ["fusion.1", 900, 200]],
+         devtrace.MODULE_LINE: [["jit_spmv_nnz(11)", 100, 300],
+                                ["jit_spmv_nnz(11)", 500, 100],
+                                ["jit_other", 900, 200]]},
     D1: {devtrace.OP_LINE: [["fusion.1", 0, 100]]},
-    HOST: {"python": [["bench.window", 0, 1000], ["bench.rebind", 0, 90],
-                      ["bench.run", 90, 700], ["bench.result", 790, 10],
-                      ["bench.run", 800, 200]]},
+    HOST: {"python": [["bench.window", 0, 1000], ["bench.rebind", 10, 80],
+                      ["bench.run", 90, 700], ["run", 350, 350],
+                      ["run.copy_in", 350, 100], ["run.execute", 450, 170],
+                      ["bench.result", 790, 10], ["bench.run", 800, 200],
+                      ["run", 850, 150], ["run.copy_in", 850, 30],
+                      ["run.execute", 880, 120]]},
 }
+IDLE_NS = {"none": 10, "bench.rebind": 80, "bench.run": 10 + 90 + 50,
+           "bench.result": 10, "run.copy_in": 50 + 30,
+           "run.execute": 50 + 20 + 20, "run": 80}
 
 
 def test_busy_is_the_union_of_op_intervals_inside_the_window():
@@ -50,9 +66,34 @@ def test_reduce_picks_the_busiest_device_and_names_idle_gaps():
     ops = dict(r["device_ops"])
     assert ops["fusion.1"] == pytest.approx(300e-9)
     assert [g[0] for g in r["idle_gaps"]] == ["bench.run", "bench.rebind",
-                                              "bench.run"]
+                                              "run.execute"]
     assert [g[1] for g in r["idle_gaps"]] == pytest.approx(
         [300e-9, 100e-9, 100e-9])
+
+
+def test_module_seconds_inside_the_window_by_module_name():
+    r = devtrace.reduce(SYNTH, [D0, D1])
+    assert r["modules"] == pytest.approx({"jit_spmv_nnz": 400e-9,
+                                          "jit_other": 100e-9})
+    assert sum(r["modules"].values()) == pytest.approx(
+        r["per_device"][D0]["busy_s"])
+
+
+def test_idle_is_cut_at_span_boundaries_and_charged_to_the_innermost():
+    r = devtrace.reduce(SYNTH, [D0, D1])
+    assert r["idle_by_span"] == pytest.approx(
+        {k: v * 1e-9 for k, v in IDLE_NS.items()})
+    assert sum(r["idle_by_span"].values()) == pytest.approx(
+        r["window_s"] - r["per_device"][D0]["busy_s"])
+
+
+def test_idle_by_span_names_every_open_span_and_skips_instants():
+    spans = [["bench.window", 0, 100], ["bench.run", 0, 100],
+             ["run", 20, 0], ["run.copy_in", 40, 20]]
+    got = devtrace.idle_by_span([["op", 40, 20]], spans, 0, 100)
+    assert got == pytest.approx({"bench.run": 80e-9, "run.copy_in": 0.0})
+    assert devtrace.idle_by_span([], [], 0, 100) == pytest.approx(
+        {"none": 100e-9})
 
 
 def test_window_must_be_traced_once():
@@ -61,9 +102,17 @@ def test_window_must_be_traced_once():
         devtrace.window(bad)
 
 
+#: Readers of the program's counters and of its spans in the trace.
+PROGRAM = ("compile_s", "h2d_mb_per_iter", "d2h_mb_per_iter",
+           "copy_in_idle_ms_per_iter", "copy_out_idle_ms_per_iter")
+
+
 def _ctx(trace, iterations=2, chips=1):
     return {"spans": [{"name": "lower", "dur_us": 2.5e6},
                       {"name": "lower.plan", "dur_us": 1e6}],
+            "setup_counters": {"jit.compile_s": 3.5, "jit.compiles": 2},
+            "counters": {"run.h2d_bytes": 3e6, "run.d2h_bytes": 1e6,
+                         "run.calls": 2},
             "iterations": iterations, "rebind_s": [0.001, 0.003],
             "trace": trace, "work": {"bytes": 819e3, "flops": 0.0},
             "peaks": {"flops": 197e12, "hbm_bytes_per_s": 819e9},
@@ -88,11 +137,32 @@ def test_readers_on_the_synthetic_trace():
     assert read["leaves_roofline"](ctx) == pytest.approx(400.0)
 
 
+def test_program_readers_on_the_synthetic_trace():
+    red = devtrace.reduce(SYNTH, [D0, D1])
+    read = {m: spec.module("layer_metrics", m).read for m in PROGRAM}
+    ctx = _ctx(red)
+    assert read["compile_s"](ctx) == pytest.approx(3.5)
+    assert read["h2d_mb_per_iter"](ctx) == pytest.approx(1.5)
+    assert read["d2h_mb_per_iter"](ctx) == pytest.approx(0.5)
+    # 80 ns of idle time under each, over 2 iterations, in ms
+    assert read["copy_in_idle_ms_per_iter"](ctx) == pytest.approx(40e-6)
+    assert read["copy_out_idle_ms_per_iter"](ctx) == pytest.approx(40e-6)
+
+
 def test_readers_return_nothing_without_a_trace():
     ctx = _ctx(None)
-    ctx["rebind_s"] = []
+    ctx.update(rebind_s=[], setup_counters={}, counters={})
     for m in ("rebind_ms_per_iter", "device_ms_per_iter", "leaves_roofline",
-              "device_idle_share", "collective_ms_per_iter"):
+              "device_idle_share", "collective_ms_per_iter") + PROGRAM:
+        assert spec.module("layer_metrics", m).read(ctx) is None
+
+
+def test_copy_idle_readers_return_nothing_without_program_spans():
+    """A trace whose window holds no kernel call of the program."""
+    bench_only = dict(SYNTH, **{HOST: {"python": [
+        sp for sp in SYNTH[HOST]["python"] if sp[0].startswith("bench.")]}})
+    ctx = _ctx(devtrace.reduce(bench_only, [D0, D1]))
+    for m in ("copy_in_idle_ms_per_iter", "copy_out_idle_ms_per_iter"):
         assert spec.module("layer_metrics", m).read(ctx) is None
 
 
